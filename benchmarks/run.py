@@ -1224,6 +1224,9 @@ BENCHES: Dict[str, Callable[[], None]] = {
 
 def main() -> None:
     import argparse
+
+    from repro.compile_cache import use_compile_cache
+    use_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument(
         "--only", default=None, metavar="SUITE[,SUITE...]",

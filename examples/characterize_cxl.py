@@ -9,8 +9,11 @@ for Intel MLC numbers against real hardware); the workflow is identical.
 """
 import numpy as np
 
+from repro.compile_cache import use_compile_cache
 from repro.core.timing import (CXLTiming, TimingConfig, calibrate,
                                latency_bandwidth_curve)
+
+use_compile_cache()
 
 # --- "hardware": an x16 Gen5 card with a slow media controller -------------
 hardware = CXLTiming(lanes=16, pcie_gen=5, backend_ns=160.0,

@@ -5,9 +5,12 @@ ONE vmapped device program, not a Python loop of runs.
 
     PYTHONPATH=src python examples/quickstart.py
 """
+from repro.compile_cache import use_compile_cache
 from repro.core import CXLRAMSim, SimConfig
 from repro.core import cache as cache_mod
 from repro.core import numa
+
+use_compile_cache()
 
 # a host with 16 GiB DRAM and one 16 GiB CXL expander card on the I/O bus
 sim = CXLRAMSim(SimConfig(

@@ -6,9 +6,11 @@ motivating LLM use-case end to end.
 """
 import sys
 
+from repro.compile_cache import use_compile_cache
 from repro.launch import serve
 
 if __name__ == "__main__":
+    use_compile_cache()
     sys.argv = ["serve", "--requests", "6", "--prefill", "48",
                 "--decode", "12", "--page-size", "8",
                 "--hbm-pages", "18"] + sys.argv[1:]

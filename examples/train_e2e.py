@@ -9,9 +9,11 @@ Default is a ~20M-param model sized for this CPU container; pass
 """
 import sys
 
+from repro.compile_cache import use_compile_cache
 from repro.launch import train
 
 if __name__ == "__main__":
+    use_compile_cache()
     argv = sys.argv[1:]
     hundred = "--hundred-m" in argv
     argv = [a for a in argv if a != "--hundred-m"]

@@ -504,8 +504,10 @@ class ShardedExecutor:
                    for k, v in scal.items()})
             outs.append(out)
         jax.block_until_ready(outs)
+        # gather on the host: the shards live on different devices
         return tiering_dyn.DynOutputs(*(
-            jnp.concatenate([getattr(o, f) for o in outs], axis=0)[:b]
+            np.concatenate([np.asarray(getattr(o, f)) for o in outs],
+                           axis=0)[:b]
             for f in tiering_dyn.DynOutputs._fields))
 
 

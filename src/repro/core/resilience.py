@@ -54,6 +54,7 @@ import pathlib
 import time
 from typing import Any, Dict, List, Optional, Tuple
 
+import jax
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
@@ -93,16 +94,29 @@ class RunKilled(BaseException):
 
 FAULT_KINDS = ("crash", "transient", "oom", "device_lost", "slow")
 
+#: XLA status codes of a program that can never run as written.
+_PROGRAM_ERRORS = ("INVALID_ARGUMENT", "UNIMPLEMENTED",
+                   "FAILED_PRECONDITION")
+#: Phrases of a compiler that refused the program (XLA:TPU, Mosaic).
+_COMPILE_ERRORS = ("compile permanent error", "failed to compile",
+                   "compilation failed", "compilation failure")
+
 
 def classify_failure(exc: BaseException) -> str:
     """Map an exception to a recovery action.
 
     Returns one of ``'oom'``, ``'transient'``, ``'device_lost'`` or
     ``'fatal'``.  Injected types map directly; real XLA runtime errors
-    are classified by message (``RESOURCE_EXHAUSTED`` / out-of-memory →
-    OOM, everything else transient — the retry budget bounds how long a
-    genuinely broken program is retried).  Anything else is fatal and
-    re-raised unchanged.
+    are classified by message.  Running out of device memory
+    (``RESOURCE_EXHAUSTED`` / out of memory) is OOM, whether the
+    allocation failed at run time or the compiler found the program too
+    big for HBM: a narrower segment may fit.  Any other compiler refusal
+    (XLA:TPU's and Mosaic's phrases in :data:`_COMPILE_ERRORS`) or a
+    status that marks the program itself as wrong (``INVALID_ARGUMENT``,
+    ``UNIMPLEMENTED``, ``FAILED_PRECONDITION``) is fatal: running the
+    same program again fails the same way.  Anything else is transient,
+    and the retry budget bounds how long it is retried.  Anything that
+    is not an XLA error is fatal and re-raised unchanged.
     """
     if isinstance(exc, SimulatedOOM):
         return "oom"
@@ -110,12 +124,14 @@ def classify_failure(exc: BaseException) -> str:
         return "device_lost"
     if isinstance(exc, TransientDeviceError):
         return "transient"
-    name = type(exc).__name__
-    if name in ("XlaRuntimeError", "JaxRuntimeError"):
+    if isinstance(exc, jax.errors.JaxRuntimeError):
         msg = str(exc)
-        if "RESOURCE_EXHAUSTED" in msg or "Out of memory" in msg \
-                or "out of memory" in msg:
+        low = msg.lower()
+        if "RESOURCE_EXHAUSTED" in msg or "out of memory" in low:
             return "oom"
+        if msg.startswith(_PROGRAM_ERRORS) or any(
+                phrase in low for phrase in _COMPILE_ERRORS):
+            return "fatal"
         return "transient"
     return "fatal"
 

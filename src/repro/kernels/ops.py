@@ -1,10 +1,10 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-Each op auto-selects `interpret` mode: compiled kernels on TPU backends,
-Python-interpreted bodies elsewhere (this container is CPU-only; TPU v5e is
-the target).  Model code calls these; pure-JAX fallbacks (`*_jnp`) are what
-the multi-pod dry-run lowers, since Pallas TPU kernels cannot lower on the
-CPU host platform.
+Each op selects `interpret` mode from the default backend: compiled
+kernels on a TPU (v5e is the target), Python-interpreted bodies on the CPU
+(the tests), and an error on any other backend.  Model code calls these;
+pure-JAX fallbacks (`*_jnp`) are what the multi-pod dry-run lowers, since
+Pallas TPU kernels cannot lower on the CPU host platform.
 """
 from __future__ import annotations
 
@@ -26,7 +26,13 @@ Array = jax.Array
 
 
 def _interpret() -> bool:
-    return jax.default_backend() != "tpu"
+    """Interpret on the CPU, compile on a TPU; refuse any other backend."""
+    backend = jax.default_backend()
+    if backend not in ("cpu", "tpu"):
+        raise RuntimeError(
+            f"Pallas kernels run compiled on a TPU or interpreted on the "
+            f"CPU; the default backend is {backend!r}")
+    return backend == "cpu"
 
 
 def cache_sim(addr: Array, *, n_sets: int, n_ways: int, chunk: int = 512):

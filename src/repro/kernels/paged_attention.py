@@ -9,7 +9,7 @@ gather with online-softmax attention so gathered K/V tiles never round-trip
 through HBM.
 
 TPU-native design: grid = (batch,); the page pool stays in ANY/HBM memory
-space and each page is pulled with a dynamic `pl.load` (async-copy on real
+space and each page is pulled by dynamic ref indexing (async-copy on real
 TPUs, emulated in interpret mode); per-sequence (m, l, acc) statistics live
 in VMEM scratch; the per-page masked online-softmax update is identical to
 flash attention's.  GQA: H query heads share K kv heads (H % K == 0).
@@ -44,8 +44,8 @@ def _paged_kernel(q_ref, bt_ref, len_ref, kp_ref, vp_ref, o_ref,
     def blk_step(j, _):
         def compute():
             pid = bt_ref[0, j]
-            k = pl.load(kp_ref, (pid,))                 # (page, kh, d)
-            v = pl.load(vp_ref, (pid,))
+            k = kp_ref[pid]                             # (page, kh, d)
+            v = vp_ref[pid]
             kf = k.astype(jnp.float32)
             vf = v.astype(jnp.float32)
             # logits: (h, page) via grouped heads

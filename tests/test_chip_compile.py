@@ -1,0 +1,108 @@
+"""The main path's device programs compile for a TPU v5e that is only
+described, not attached.
+
+The TPU compiler ships with jaxlib, so these tests run on any host: they
+catch a program the chip's compiler would refuse (a tiling rule, a
+memory limit) before any chip time is spent.  Nothing runs, so they say
+nothing about results or speed.
+
+Geometry is the paper's Table-I host (4 cores, 64 KiB 8-way L1, 2 MiB
+16-way L2) with five route targets (``switched(4)``); batch and segment
+are cut so each program compiles in seconds.  The Pallas MESI kernels
+are not here: Mosaic refuses their (1, chunk) trace blocks, which break
+the 8x128 tiling rule.  One program too big for the chip's HBM checks
+that the compiler's refusal reads as an out-of-memory to the resilient
+executor, which then narrows the segment instead of giving up.
+"""
+import functools
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.core import cache as cache_mod
+from repro.core import engine, resilience, tiering_dyn
+
+PARAMS = cache_mod.CacheParams(cores=4, n_targets=5)
+BATCH = 8
+SLOT = 4096                       # DynamicTiering().epoch_len
+# STREAM triad's pages at 2 x L2: at 8 x L2 (4,098 pages) the dynamic
+# program takes ~12 s to compile
+PAGES = 2 * 2 * 1024 * 1024 // 4096 + 2
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    # libtpu would otherwise write its logs under /tmp
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:     # no TPU compiler in this jaxlib
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture
+def no_compile_cache():
+    """A described chip's programs can be written to the persistent cache
+    but never read back; keep them out of it."""
+    from jax.experimental.compilation_cache import compilation_cache
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _shapes(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, jnp.int32,
+                                       sharding=sharding), tree)
+
+
+def test_static_segment_compiles(one_chip, no_compile_cache):
+    carry = _shapes(jax.eval_shape(
+        functools.partial(engine.init_batch_carry, PARAMS, BATCH)), one_chip)
+    trace = [jax.ShapeDtypeStruct((BATCH, 2 * SLOT), jnp.int32,
+                                  sharding=one_chip)] * 4
+    compiled = engine._segment_stepper(True).lower(
+        PARAMS, carry, *trace).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_dynamic_segment_compiles(one_chip, no_compile_cache):
+    page_map0 = jax.ShapeDtypeStruct((BATCH, PAGES), jnp.int32)
+    carry = _shapes(jax.eval_shape(
+        functools.partial(tiering_dyn.init_dyn_carry, PARAMS), page_map0),
+        one_chip)
+
+    def arr(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+
+    trace = [arr(BATCH, 1, SLOT)] * 4
+    scalars = ([arr(BATCH)] * 8 + [arr(BATCH, PAGES, PARAMS.n_targets)]
+               + [arr(BATCH)] * 3)
+    compiled = tiering_dyn._dyn_segment_stepper(True).lower(
+        PARAMS, 8, SLOT + 1, carry, *trace, *scalars).compile()
+    assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+def test_program_too_big_for_hbm_reads_as_oom(one_chip, no_compile_cache):
+    """XLA:TPU refuses a program whose buffers exceed HBM while it
+    compiles; that refusal must degrade (a narrower segment may fit),
+    not be fatal like other compile errors."""
+    x = jax.ShapeDtypeStruct((2 ** 15, 2 ** 16), jnp.float32,
+                             sharding=one_chip)            # 8 GiB
+
+    def reversed_copies(x):
+        return (x * 2.0)[::-1] + (x * 3.0)[:, ::-1] + (x * 5.0)[::-1, ::-1]
+
+    with pytest.raises(jax.errors.JaxRuntimeError) as err:
+        jax.jit(reversed_copies).lower(x).compile()
+    assert "memory space hbm" in str(err.value)
+    assert resilience.classify_failure(err.value) == "oom"

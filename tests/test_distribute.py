@@ -9,6 +9,11 @@ the resident scan entry-for-entry (stats and final cache state).  The
 path (the golden fixtures additionally pin the sharded+streamed rows —
 see tests/test_golden_stats.py).
 """
+import os
+import pathlib
+import subprocess
+import sys
+
 import numpy as np
 import pytest
 
@@ -203,3 +208,70 @@ def test_streaming_validation():
     with pytest.raises(ValueError):
         engine.run_traces(CACHE, np.zeros((1, 8), np.int32), None,
                           segment=0)
+
+
+_FOUR_DEVICE_SCRIPT = """
+import sys
+from collections import Counter
+
+import jax
+import numpy as np
+from repro.core import distribute, engine, numa, tiering_dyn
+from repro.core import cache as C
+from repro.core import route as route_mod
+from repro.core.tiering_dyn import DynamicTiering
+from repro.core.timing import TimingConfig
+
+assert len(jax.devices()) == 4
+cache = C.CacheParams(l1_bytes=8 * 1024, l1_ways=2, l2_bytes=16 * 1024,
+                      l2_ways=8)
+program = sys.argv[1]
+# four static rows (the pmap path), or eight epoch-program rows (the
+# round-robin path): two real rows a device
+spec = engine.SweepSpec(
+    footprint_factors=(1, 2), policies=(numa.ZNuma(1.0),),
+    topologies=(route_mod.direct(1), route_mod.direct(2)),
+    tiering=(() if program == "static"
+             else (None, DynamicTiering(epoch_len=512))))
+live = Counter()       # device id -> rows with non-zero counters
+
+def record(stats):
+    for shard in stats.addressable_shards:
+        data = np.asarray(shard.data).reshape(-1, stats.shape[-1])
+        live[shard.device.id] += int((data != 0).any(-1).sum())
+
+mod, name, stats_of = (
+    (distribute, "_pmap_segment", lambda out: out[2])
+    if program == "static"
+    else (tiering_dyn, "run_dynamic", lambda out: out.stats))
+fn = getattr(mod, name)
+
+def spy(*args, **kw):
+    out = fn(*args, **kw)
+    record(stats_of(out))
+    return out
+
+setattr(mod, name, spy)
+sharded = distribute.run_sweep(spec, cache, TimingConfig(), mesh=4)
+setattr(mod, name, fn)
+rows = 4 if program == "static" else 8
+assert dict(live) == {d: rows // 4 for d in range(4)}, dict(live)
+assert sharded == engine.run_sweep(spec, cache, TimingConfig())
+"""
+
+
+@pytest.mark.parametrize("program", ["static", "dynamic"])
+def test_four_devices_each_get_a_shard_with_parity(program):
+    """Static rows pmap over every device, epoch-program shards land on
+    every device round-robin and gather back on the host (a concatenate
+    across devices is refused); each device simulates real rows and the
+    rows equal the one-device sweep.  Needs its own process for four
+    virtual CPU devices."""
+    src = pathlib.Path(__file__).resolve().parents[1] / "src"
+    env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(src),
+           "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
+    res = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_SCRIPT,
+                          program],
+                         env=env, capture_output=True, text=True,
+                         timeout=300)
+    assert res.returncode == 0, res.stderr[-3000:]

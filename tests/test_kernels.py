@@ -14,6 +14,19 @@ def randn(shape, dtype=jnp.float32):
     return jnp.asarray(RNG.standard_normal(shape), dtype)
 
 
+@pytest.mark.parametrize("backend,interpret", [
+    ("cpu", True), ("tpu", False), ("gpu", None)])
+def test_interpret_mode_follows_backend(monkeypatch, backend, interpret):
+    """Interpret on the CPU, compile on a TPU, and no silent fallback to
+    the interpreter anywhere else."""
+    monkeypatch.setattr(jax, "default_backend", lambda: backend)
+    if interpret is None:
+        with pytest.raises(RuntimeError, match="'gpu'"):
+            ops._interpret()
+    else:
+        assert ops._interpret() is interpret
+
+
 # ---------------------------------------------------------------------------
 # cache_sim
 # ---------------------------------------------------------------------------

@@ -191,6 +191,23 @@ def test_oom_degradation_parity(spec_fn):
     assert report.degradations == 2
 
 
+@pytest.mark.parametrize("spec_fn", [grid_spec, dyn_spec])
+def test_resilient_segments_never_donate_the_carry(monkeypatch, spec_fn):
+    """A retried or degraded segment re-runs from the carry it was given,
+    so that carry must outlive the call.  Off the CPU a donated carry is
+    deleted by the call (a v5e does so), whether or not the call fails."""
+    from repro.core import tiering_dyn
+    asked = []
+    for mod, name in ((engine, "run_batch_segment"),
+                      (tiering_dyn, "run_dynamic_segment")):
+        def spy(*args, _fn=getattr(mod, name), **kw):
+            asked.append(kw.get("donate", False))
+            return _fn(*args, **kw)
+        monkeypatch.setattr(mod, name, spy)
+    run_resilient(spec_fn(), report=RunReport())
+    assert asked and not any(asked)
+
+
 def test_oom_at_minimum_width_raises():
     spec = grid_spec()
     ex = distribute.ResilientExecutor(
@@ -319,6 +336,33 @@ def test_resume_refuses_a_different_execution_plan(tmp_path):
 # ---------------------------------------------------------------------------
 # FaultPlan / RunReport unit behavior
 # ---------------------------------------------------------------------------
+@pytest.mark.parametrize("msg,kind", [
+    ("RESOURCE_EXHAUSTED: Out of memory while trying to allocate 8.00G.",
+     "oom"),
+    ("RESOURCE_EXHAUSTED: Error allocating device buffer: Attempting to "
+     "allocate 32.00G. That was not possible. There are 15.75G free.",
+     "oom"),
+    # too wide for HBM at compile time: a narrower segment may fit
+    ("RESOURCE_EXHAUSTED: XLA:TPU compile permanent error. Ran out of "
+     "memory in memory space hbm.", "oom"),
+    ("INTERNAL: Mosaic failed to compile TPU kernel: unsupported op",
+     "fatal"),
+    ("INTERNAL: XLA:TPU compile permanent error. Unsupported layout.",
+     "fatal"),
+    ("UNIMPLEMENTED: no lowering for this op", "fatal"),
+    ("INVALID_ARGUMENT: shape mismatch", "fatal"),
+    ("UNAVAILABLE: TPU device is resetting", "transient"),
+    ("INTERNAL: stream did not block host until done", "transient"),
+    ("INTERNAL: the compiled program's launch timed out", "transient"),
+])
+def test_classify_real_xla_errors(msg, kind):
+    """Compiler refusals and wrong programs are never retried; running
+    out of HBM, at compile time too, degrades."""
+    import jax
+    assert resilience.classify_failure(jax.errors.JaxRuntimeError(msg)) \
+        == kind
+
+
 def test_fault_validation():
     with pytest.raises(ValueError, match="unknown fault kind"):
         Fault("meteor", shard=0)

@@ -9,13 +9,15 @@ asserts the rows are bitwise-identical to an uninterrupted run:
 
     PYTHONPATH=src python tools/resilience_smoke.py
 
-Flow: the parent computes the expected rows (plain streamed sweep),
-spawns a child running the same sweep with per-segment checkpoints and
-a deliberate per-segment slowdown (so the kill window is wide), waits
-for the first `step_*` directory to appear, SIGKILLs the child, then
+Flow: the parent spawns a child running the sweep with per-segment
+checkpoints and a deliberate per-segment slowdown (so the kill window is
+wide), waits for the first `step_*` directory to appear, SIGKILLs the
+child, then computes the expected rows (plain streamed sweep) and
 resumes from the checkpoint directory.  A child that finishes before
 the kill lands degrades to a pure fast-forward resume — still a pass
-(the parity assertion is identical).  See docs/resilience.md.
+(the parity assertion is identical).  The parent imports nothing of JAX
+until the child has exited: an accelerator belongs to one process at a
+time.  See docs/resilience.md.
 """
 from __future__ import annotations
 
@@ -72,13 +74,6 @@ def _first_checkpoint(ckdir: pathlib.Path):
 
 
 def parent_main() -> int:
-    from repro.core import distribute
-    from repro.core.resilience import RunReport
-
-    spec, cache, timing = _sim_inputs()
-    expected = distribute.run_sweep(spec, cache, timing,
-                                    stream_chunk=STREAM_CHUNK)
-
     with tempfile.TemporaryDirectory() as d:
         ckdir = pathlib.Path(d)
         child = subprocess.Popen(
@@ -105,6 +100,12 @@ def parent_main() -> int:
               f"(rc={rc}); checkpoints present: "
               f"{sorted(p.name for p in ckdir.glob('shard_*/step_*'))}")
 
+        from repro.core import distribute
+        from repro.core.resilience import RunReport
+
+        spec, cache, timing = _sim_inputs()
+        expected = distribute.run_sweep(spec, cache, timing,
+                                        stream_chunk=STREAM_CHUNK)
         report = RunReport()
         resumed = distribute.run_sweep(spec, cache, timing,
                                        stream_chunk=STREAM_CHUNK,
