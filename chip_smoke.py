@@ -21,6 +21,9 @@ Four chips: only the two sweeps sharded over four devices against the
 same sweeps on one device; rows bitwise equal, and every chip ran a
 shard.
 
+The program's span recorder (``repro.core.obs``) is on throughout; its
+counters give the compile-cache hits and misses the lines report.
+
 Every line but the last records the run on the device it names and
 claims nothing.  The last line is ``{"ok": true, "device": {...}}``; any
 failure exits non-zero without it.  Runs in one process: a chip belongs
@@ -150,21 +153,13 @@ class DeviceRows:
                 setattr(mod, name, fn)
 
 
-class CacheEvents:
-    """Hits and misses of JAX's persistent compilation cache."""
-
-    def __init__(self):
-        import jax
-        self.seen = collections.Counter()
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_event(self, event, **_kw) -> None:
-        if event.startswith("/jax/compilation_cache/cache_"):
-            self.seen[event.rsplit("_", 1)[-1]] += 1
-
-    def __str__(self) -> str:
-        return (f"compile cache {self.seen['hits']} hits, "
-                f"{self.seen['misses']} misses so far")
+def _cache_events() -> str:
+    """Hits and misses of JAX's persistent compilation cache, as the
+    program's recorder (``repro.core.obs``) counted them."""
+    from repro.core import obs
+    seen = obs.totals()
+    return (f"compile cache {seen.get('cache_hits', 0)} hits, "
+            f"{seen.get('cache_misses', 0)} misses so far")
 
 
 def _check(ok: bool, msg: str) -> None:
@@ -179,7 +174,7 @@ def _timed(fn):
     return out, time.perf_counter() - t
 
 
-def one_chip(dev, cache_events) -> None:
+def one_chip(dev) -> None:
     import jax
 
     from repro.core import resilience
@@ -194,7 +189,7 @@ def one_chip(dev, cache_events) -> None:
         print(f"{tag} {name} sweep: {len(rows)} rows in "
               f"{sum(spy.rows.values())} device rows, {acc} simulated "
               f"accesses, cold {cold:.1f} s (compile included); "
-              f"{cache_events}", flush=True)
+              f"{_cache_events()}", flush=True)
         if name == "mixed":
             again, warm = _timed(lambda: sim.sweep(**grid))
             _check(again == rows, "a repeated sweep changed its rows")
@@ -256,7 +251,7 @@ def one_chip(dev, cache_events) -> None:
               f"{kind}): {first}", flush=True)
     else:
         raise RuntimeError("backend='pallas' ran; update this check")
-    print(f"{tag} {cache_events}", flush=True)
+    print(f"{tag} {_cache_events()}", flush=True)
 
 
 def four_chips(devices) -> None:
@@ -311,13 +306,14 @@ def main() -> int:
     from repro.compile_cache import use_compile_cache
     print(f"{_label(dev)} x{len(devices)}: compile cache "
           f"{use_compile_cache()}", flush=True)
-    cache_events = CacheEvents()
+    from repro.core import obs
+    obs.enable()
     for cut in CUTS:
         print(f"{_label(dev)} grid cut: {cut}", flush=True)
     if args.chips == 4:
         four_chips(devices[:4])
     else:
-        one_chip(dev, cache_events)
+        one_chip(dev)
     print(json.dumps({"ok": True, "device": {
         "platform": dev.platform, "kind": dev.device_kind,
         "count": len(devices)}}))

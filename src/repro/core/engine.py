@@ -56,6 +56,7 @@ import numpy as np
 
 from repro.core import cache as cache_mod
 from repro.core import numa as numa_mod
+from repro.core import obs
 from repro.core import route as route_mod
 from repro.core import sampling as sampling_mod
 from repro.core import tiering_dyn
@@ -450,27 +451,36 @@ def run_traces(p: cache_mod.CacheParams, addr, is_write,
 
     Returns: (stats (B, nstats(p.n_targets)) int32, batched CacheState).
     """
-    addr = jnp.asarray(addr, jnp.int32)
-    if addr.ndim != 2:
-        raise ValueError("run_traces expects a (B, N) batch; "
-                         "use addr[None] for a single trace")
-    if backend not in BACKENDS:
-        raise ValueError(f"unknown backend {backend!r}; pick from {BACKENDS}")
-    z = jnp.zeros(addr.shape, jnp.int32)
-    is_write = z if is_write is None else jnp.asarray(is_write, jnp.int32)
-    core = z if core is None else jnp.asarray(core, jnp.int32)
-    tier = z if tier is None else jnp.asarray(tier, jnp.int32)
+    with obs.span("sweep.prep") as sp:
+        addr = jnp.asarray(addr, jnp.int32)
+        if addr.ndim != 2:
+            raise ValueError("run_traces expects a (B, N) batch; "
+                             "use addr[None] for a single trace")
+        if backend not in BACKENDS:
+            raise ValueError(
+                f"unknown backend {backend!r}; pick from {BACKENDS}")
+        z = jnp.zeros(addr.shape, jnp.int32)
+        is_write = z if is_write is None else jnp.asarray(is_write,
+                                                          jnp.int32)
+        core = z if core is None else jnp.asarray(core, jnp.int32)
+        tier = z if tier is None else jnp.asarray(tier, jnp.int32)
+        sp.ready((addr, is_write, core, tier))
     if segment is not None:
         return _run_traces_segmented(p, addr, is_write, core, tier,
                                      segment=segment, backend=backend,
                                      chunk=chunk)
-    if backend == "reference":
-        return _run_batch_reference(p, addr, is_write, core, tier)
-    if backend == "pallas":
-        from repro.kernels import ops
-        return ops.mesi_cache_sim(addr, is_write, core, tier,
-                                  params=p, chunk=chunk)
-    raise ValueError(f"unknown backend {backend!r}; pick from {BACKENDS}")
+    with obs.span("sweep.program") as sp:
+        if backend == "reference":
+            out = _run_batch_reference(p, addr, is_write, core, tier)
+        else:
+            from repro.kernels import ops
+            out = ops.mesi_cache_sim(addr, is_write, core, tier,
+                                     params=p, chunk=chunk)
+        sp.ready(out)
+        if sp:
+            sp.add(program="static", row_steps=addr.shape[0] * addr.shape[1],
+                   segments=1)
+    return out
 
 
 def _pad_to_segment(x: Array, n_to: int, fill: int) -> Array:
@@ -502,16 +512,22 @@ def _run_traces_segmented(p: cache_mod.CacheParams, addr: Array,
     b, n = addr.shape
     segment = min(segment, n)   # never pad beyond the trace itself
     n_pad = -(-n // segment) * segment
-    addr = _pad_to_segment(addr, n_pad, SENTINEL)
-    is_write = _pad_to_segment(is_write, n_pad, 0)
-    core = _pad_to_segment(core, n_pad, 0)
-    tier = _pad_to_segment(tier, n_pad, 0)
-    carry = init_batch_carry(p, b)
-    for s in range(0, n_pad, segment):
-        carry = run_batch_segment(
-            p, carry, addr[:, s:s + segment], is_write[:, s:s + segment],
-            core[:, s:s + segment], tier[:, s:s + segment], donate=True,
-            backend=backend, chunk=chunk)
+    with obs.span("sweep.program") as sp:
+        addr = _pad_to_segment(addr, n_pad, SENTINEL)
+        is_write = _pad_to_segment(is_write, n_pad, 0)
+        core = _pad_to_segment(core, n_pad, 0)
+        tier = _pad_to_segment(tier, n_pad, 0)
+        carry = init_batch_carry(p, b)
+        for s in range(0, n_pad, segment):
+            carry = run_batch_segment(
+                p, carry, addr[:, s:s + segment],
+                is_write[:, s:s + segment], core[:, s:s + segment],
+                tier[:, s:s + segment], donate=True, backend=backend,
+                chunk=chunk)
+        sp.ready(carry)
+        if sp:
+            sp.add(program="static", row_steps=b * n_pad,
+                   segments=n_pad // segment)
     l1p, l2p, stats, _ = carry
     return stats, cache_mod.unpack_state(l1p, l2p)
 
@@ -577,11 +593,38 @@ def build_sweep_batch(spec: SweepSpec, cache: cache_mod.CacheParams,
     """
     if routes is None:
         routes = [None] * len(spec.topology_axis)
-    # the trace depends only on (workload, footprint); generate once
+    with obs.span("sweep.build") as sp:
+        batch, cell_rows = _build_sweep_batch(spec, cache, chunk, routes)
+        sp.ready(vars(batch))
+        _count_batch(sp, batch)
+    return batch, cell_rows
+
+
+def _count_batch(sp, batch: TraceBatch) -> None:
+    """The ``sweep.build`` counters of a stacked batch."""
+    if sp:
+        sp.add(rows=batch.batch, steps=batch.length,
+               accesses=batch.total_accesses)
+
+
+def _device_traces(spec: SweepSpec, cache: cache_mod.CacheParams) -> Dict:
+    """Each (workload, footprint) of the grid's trace, generated once."""
     cell_traces = {}
     for wl, k, _ in spec.sim_cells:
         if (wl, k) not in cell_traces:
-            cell_traces[(wl, k)] = wl.device_trace(k * cache.l2_bytes)
+            with obs.span("sweep.build.trace") as sp:
+                wt = wl.device_trace(k * cache.l2_bytes)
+                sp.ready(vars(wt))
+                if sp:
+                    sp.add(workload=wl.name,
+                           accesses=int(wt.addr.shape[0]))
+            cell_traces[(wl, k)] = wt
+    return cell_traces
+
+
+def _build_sweep_batch(spec, cache, chunk, routes):
+    """The body of :func:`build_sweep_batch`."""
+    cell_traces = _device_traces(spec, cache)
     traces: List[Tuple] = []
     row_of = {}
     cell_rows: List[int] = []
@@ -792,16 +835,30 @@ def sweep_results(spec: SweepSpec, cache: cache_mod.CacheParams,
         One per grid row, ordered tiering-major, then topology,
         workload, footprint, policy, cpu.
     """
-    if spec.backend not in BACKENDS:
-        raise ValueError(f"unknown backend {spec.backend!r}")
-    executor = _resolve_executor(executor, resume, fault_plan, report)
-    executor = executor if executor is not None else _LOCAL_EXECUTOR
-    routes = [None if tp is None else route_mod.build_route(tp, timing)
-              for tp in spec.topology_axis]
-    if (any(tr is not None for tr in spec.tiering_axis)
-            or any(sp is not None for sp in spec.sampling_axis)):
-        return _sweep_results_dynamic(spec, cache, timing, routes,
-                                      executor=executor)
+    with obs.span("sweep") as sweep_span:
+        if spec.backend not in BACKENDS:
+            raise ValueError(f"unknown backend {spec.backend!r}")
+        executor = _resolve_executor(executor, resume, fault_plan, report)
+        executor = executor if executor is not None else _LOCAL_EXECUTOR
+        routes = [None if tp is None else route_mod.build_route(tp, timing)
+                  for tp in spec.topology_axis]
+        if (any(tr is not None for tr in spec.tiering_axis)
+                or any(sp is not None for sp in spec.sampling_axis)):
+            out = _sweep_results_dynamic(spec, cache, timing, routes,
+                                         executor=executor)
+        else:
+            out = _sweep_results_static(spec, cache, timing, routes,
+                                        chunk=chunk, executor=executor)
+        if sweep_span:
+            sweep_span.add(rows=len(out))
+    return out
+
+
+def _sweep_results_static(spec: SweepSpec, cache: cache_mod.CacheParams,
+                          timing: TimingConfig,
+                          routes: Sequence[Optional[route_mod.RouteMap]],
+                          *, chunk: int, executor) -> List[RunResult]:
+    """The static-program body of `sweep_results`."""
     t_max = max(2 if r is None else r.n_targets for r in routes)
     p = dataclasses.replace(cache, n_targets=t_max)
     batch, cell_rows = build_sweep_batch(spec, cache, chunk=chunk,
@@ -907,11 +964,17 @@ def build_tiering_batch(spec: SweepSpec, cache: cache_mod.CacheParams,
     -------
     TieringBatch
     """
+    with obs.span("sweep.build") as sp:
+        tb = _build_tiering_batch(spec, cache, routes, slot, t_max)
+        sp.ready((vars(tb), vars(tb.batch)))
+        _count_batch(sp, tb.batch)
+    return tb
+
+
+def _build_tiering_batch(spec, cache, routes, slot, t_max):
+    """The body of :func:`build_tiering_batch`."""
     cells = spec.sim_cells
-    cell_traces = {}
-    for wl, k, _ in cells:
-        if (wl, k) not in cell_traces:
-            cell_traces[(wl, k)] = wl.device_trace(k * cache.l2_bytes)
+    cell_traces = _device_traces(spec, cache)
     p_max = max(wt.n_pages for wt in cell_traces.values())
     ptl_of = []
     for route in routes:

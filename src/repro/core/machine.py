@@ -25,6 +25,7 @@ import numpy as np
 
 from repro.core import cache as cache_sim
 from repro.core import numa as numa_mod
+from repro.core import obs
 from repro.core.spec import CACHELINE_BYTES
 from repro.core.switch import shared_usp_latency_ns
 from repro.core.timing import LatencyDistribution, TimingConfig
@@ -348,6 +349,15 @@ def time_batch(timing: TimingConfig, cpus: Sequence[CPUModel],
     list of RunResult
         One per row.
     """
+    with obs.span("sweep.timing") as sp:
+        out = _time_batch(timing, cpus, stats, route, mig_lines, dist)
+        if sp:
+            sp.add(rows=len(out))
+    return out
+
+
+def _time_batch(timing, cpus, stats, route, mig_lines, dist):
+    """The body of :func:`time_batch`."""
     stats = np.asarray(stats, np.int64)
     if route is None:
         kinds = ["dram", "cxl"]

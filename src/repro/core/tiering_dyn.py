@@ -72,6 +72,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.core import cache as cache_mod
+from repro.core import obs
 from repro.core.numa import LINES_PER_PAGE
 
 Array = jax.Array
@@ -523,51 +524,54 @@ def prep_dynamic_inputs(addr, is_write, core, tier, *, slot_len: int,
     threshold, period, dram_cap, ssd_tid, cxl_cap, page_target_lines,
     s_warm, s_meas, s_per)``.
     """
-    addr = jnp.asarray(addr, jnp.int32)
-    if addr.ndim != 2:
-        raise ValueError("run_dynamic expects a (B, N) batch")
-    b, n = addr.shape
-    if n % slot_len != 0:
-        raise ValueError(f"trace length {n} is not a multiple of the "
-                         f"epoch slot length {slot_len}")
-    n_p = int(jnp.asarray(page_map0).shape[1])
-    # a budget beyond the page count can never be spent: clamp the top-k
-    # width to P (lax.top_k rejects k > minor dimension)
-    k_max = min(int(k_max), n_p)
-    # counts reset every epoch, so the coldness-key bound only needs to
-    # exceed the longest epoch (not the trace)
-    count_bound = int(np.max(np.asarray(period))) * slot_len + 1
-    if (count_bound + 1) * n_p + n_p >= 2 ** 31:
-        raise ValueError(
-            f"epoch hotness keys overflow int32: epoch_len * n_pages = "
-            f"{(count_bound - 1) * n_p}; shrink the epoch or page count")
-    e = n // slot_len
-    shape3 = (b, e, slot_len)
+    with obs.span("sweep.prep") as sp:
+        addr = jnp.asarray(addr, jnp.int32)
+        if addr.ndim != 2:
+            raise ValueError("run_dynamic expects a (B, N) batch")
+        b, n = addr.shape
+        if n % slot_len != 0:
+            raise ValueError(f"trace length {n} is not a multiple of the "
+                             f"epoch slot length {slot_len}")
+        n_p = int(jnp.asarray(page_map0).shape[1])
+        # a budget beyond the page count can never be spent: clamp the top-k
+        # width to P (lax.top_k rejects k > minor dimension)
+        k_max = min(int(k_max), n_p)
+        # counts reset every epoch, so the coldness-key bound only needs to
+        # exceed the longest epoch (not the trace)
+        count_bound = int(np.max(np.asarray(period))) * slot_len + 1
+        if (count_bound + 1) * n_p + n_p >= 2 ** 31:
+            raise ValueError(
+                f"epoch hotness keys overflow int32: epoch_len * n_pages = "
+                f"{(count_bound - 1) * n_p}; shrink the epoch or page count")
+        e = n // slot_len
+        shape3 = (b, e, slot_len)
 
-    def r3(x):
-        return jnp.asarray(x, jnp.int32).reshape(shape3)
+        def r3(x):
+            return jnp.asarray(x, jnp.int32).reshape(shape3)
 
-    z = jnp.zeros((b, n), jnp.int32)
-    a3 = r3(addr)
-    w3 = r3(z if is_write is None else is_write)
-    c3 = r3(z if core is None else core)
-    t3 = r3(z if tier is None else tier)
-    zb = jnp.zeros((b,), jnp.int32)
-    scalars = (jnp.asarray(dyn_flag, jnp.int32),
-               jnp.asarray(n_pages, jnp.int32),
-               jnp.asarray(budget, jnp.int32),
-               jnp.asarray(threshold, jnp.int32),
-               jnp.asarray(period, jnp.int32),
-               jnp.asarray(dram_cap, jnp.int32),
-               zb if ssd_tid is None else jnp.asarray(ssd_tid, jnp.int32),
-               (jnp.full((b,), UNBOUNDED_PAGES, jnp.int32)
-                if cxl_cap is None else jnp.asarray(cxl_cap, jnp.int32)),
-               jnp.asarray(page_target_lines, jnp.int32),
-               zb if s_warm is None else jnp.asarray(s_warm, jnp.int32),
-               zb if s_meas is None else jnp.asarray(s_meas, jnp.int32),
-               zb if s_per is None else jnp.asarray(s_per, jnp.int32))
-    return (a3, w3, c3, t3, jnp.asarray(page_map0, jnp.int32), scalars,
-            k_max, count_bound)
+        z = jnp.zeros((b, n), jnp.int32)
+        a3 = r3(addr)
+        w3 = r3(z if is_write is None else is_write)
+        c3 = r3(z if core is None else core)
+        t3 = r3(z if tier is None else tier)
+        zb = jnp.zeros((b,), jnp.int32)
+        scalars = (jnp.asarray(dyn_flag, jnp.int32),
+                   jnp.asarray(n_pages, jnp.int32),
+                   jnp.asarray(budget, jnp.int32),
+                   jnp.asarray(threshold, jnp.int32),
+                   jnp.asarray(period, jnp.int32),
+                   jnp.asarray(dram_cap, jnp.int32),
+                   zb if ssd_tid is None else jnp.asarray(ssd_tid, jnp.int32),
+                   (jnp.full((b,), UNBOUNDED_PAGES, jnp.int32)
+                    if cxl_cap is None else jnp.asarray(cxl_cap, jnp.int32)),
+                   jnp.asarray(page_target_lines, jnp.int32),
+                   zb if s_warm is None else jnp.asarray(s_warm, jnp.int32),
+                   zb if s_meas is None else jnp.asarray(s_meas, jnp.int32),
+                   zb if s_per is None else jnp.asarray(s_per, jnp.int32))
+        out = (a3, w3, c3, t3, jnp.asarray(page_map0, jnp.int32), scalars,
+               k_max, count_bound)
+        sp.ready(out)
+    return out
 
 
 def run_dynamic(p: cache_mod.CacheParams, addr, is_write, core, tier,
@@ -644,20 +648,37 @@ def run_dynamic(p: cache_mod.CacheParams, addr, is_write, core, tier,
             dram_cap=dram_cap, page_target_lines=page_target_lines,
             ssd_tid=ssd_tid, cxl_cap=cxl_cap,
             s_warm=s_warm, s_meas=s_meas, s_per=s_per)
-    e = a3.shape[1]
-    if segment_slots is None and backend == "reference":
-        return _run_dynamic(p, int(k_max), count_bound, a3, w3, c3, t3,
-                            scalars[0], page_map0, *scalars[1:])
-    if segment_slots is None:
+    b, e = a3.shape[:2]
+    if segment_slots is None and backend != "reference":
         segment_slots = e   # pallas: one kernel launch spans every slot
-    if segment_slots < 1:
+    if segment_slots is not None and segment_slots < 1:
         raise ValueError(f"segment_slots must be >= 1, got {segment_slots}")
+    with obs.span("sweep.program") as sp:
+        if segment_slots is None:
+            out = _run_dynamic(p, int(k_max), count_bound, a3, w3, c3, t3,
+                               scalars[0], page_map0, *scalars[1:])
+        else:
+            out = _run_dynamic_segmented(p, int(k_max), count_bound, a3,
+                                         w3, c3, t3, page_map0, scalars,
+                                         segment_slots, backend)
+        sp.ready(out)
+        if sp:
+            sp.add(program="epoch", row_steps=b * e * slot_len,
+                   segments=(1 if segment_slots is None
+                             else -(-e // segment_slots)))
+    return out
+
+
+def _run_dynamic_segmented(p, k_max, count_bound, a3, w3, c3, t3,
+                           page_map0, scalars, segment_slots, backend):
+    """:func:`run_dynamic` as one device call per ``segment_slots``."""
+    e = a3.shape[1]
     carry = init_dyn_carry(p, page_map0)
     slots_parts, snaps_parts, meas_parts = [], [], []
     for s in range(0, e, segment_slots):
         sl = slice(s, min(s + segment_slots, e))
         carry, slots, snaps, meas = run_dynamic_segment(
-            p, int(k_max), count_bound, carry, a3[:, sl], w3[:, sl],
+            p, k_max, count_bound, carry, a3[:, sl], w3[:, sl],
             c3[:, sl], t3[:, sl], *scalars, donate=True, backend=backend)
         slots_parts.append(slots)
         snaps_parts.append(snaps)
