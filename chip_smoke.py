@@ -10,19 +10,26 @@ One chip: the paper's Table-I host (4 cores, 64 KiB 8-way L1, 2 MiB
 sweeps.  The mixed sweep covers footprints x policies x topologies x
 workloads x static/dynamic tiering; a grid that holds a dynamic tiering
 runs every row, static ones included, through the epoch program.  The
-static sweep (no tiering axis) runs the static program over a batch.
-Each sweep is then streamed through the resilient executor (rows bitwise
-equal, no retry, degradation or eviction).  Every golden family must
-reproduce its committed row, and one static and one dynamic full-width
-row must match the same call pinned to the CPU, counter for counter.
-``backend="pallas"`` must raise, never fall back.
+static sweep (no tiering axis) runs the static program over a batch,
+which on a TPU is the compiled Pallas kernel.  Each sweep is then
+streamed through the resilient executor (rows bitwise equal, no retry,
+degradation or eviction).  Every golden family must reproduce its
+committed row, and one static and one dynamic full-width row must match
+the same call pinned to the CPU (the reference scan), counter for
+counter.  The static sweep on ``backend="pallas"`` must give the rows of
+``backend="reference"``; a dynamic-tiering sweep on ``backend="pallas"``
+must raise fatally, never fall back.
 
 Four chips: only the two sweeps sharded over four devices against the
 same sweeps on one device; rows bitwise equal, and every chip ran a
-shard.
+shard.  The sharded static sweep pmaps the Pallas segment kernel, one
+row a chip; the mixed sweep places its epoch-program shards round-robin.
 
 The program's span recorder (``repro.core.obs``) is on throughout; its
-counters give the compile-cache hits and misses the lines report.
+counters give the compile-cache hits and misses the lines report, and
+say which program and backend each sweep ran: the mixed sweep the epoch
+program on the reference scan, the static sweep the static program on
+the Pallas kernel.
 
 Every line but the last records the run on the device it names and
 claims nothing.  The last line is ``{"ok": true, "device": {...}}``; any
@@ -43,12 +50,11 @@ ROOT = pathlib.Path(__file__).resolve().parent
 STREAM_CHUNK = 65536
 
 # Cuts from the full smoke grid (footprints (2, 8); STREAM triad, pointer
-# chase, GUPS, KV decode).  At the Table-I geometry one reference scan
-# step costs about 63 us per batch row on one v5e (the compiled step
-# moves the whole lane-padded L2 state of every row), so the full grid's
-# 120 device rows x 2,097,150 steps would take about 4.4 hours per
-# sweep.  The cut grid keeps 6 device rows x 155,648 steps, about a
-# minute per sweep.
+# chase, GUPS, KV decode).  The mixed grid runs the epoch program on the
+# reference scan, at about 60 us a step per batch row on one v5e (about
+# 87 dependent XLA ops an access), so the full grid's 120 device rows x
+# 2,097,150 steps would take about 4.4 hours per sweep.  The cut grid
+# keeps 6 device rows x 155,648 steps, about a minute per sweep.
 CUTS = (
     "footprint 8 x L2",
     "STREAM triad, pointer chase and GUPS: each policy is a row of its "
@@ -162,6 +168,14 @@ def _cache_events() -> str:
             f"{seen.get('cache_misses', 0)} misses so far")
 
 
+def _last_program() -> str:
+    """Which program ran last and on which backend, as the newest
+    ``sweep.program`` span of the program's recorder names them."""
+    from repro.core import obs
+    rec = [r for r in obs.records() if r.name == "sweep.program"][-1]
+    return f"{rec.counters['program']} program on {rec.counters['backend']}"
+
+
 def _check(ok: bool, msg: str) -> None:
     if not ok:
         raise RuntimeError(msg)
@@ -186,7 +200,11 @@ def one_chip(dev) -> None:
         with spy.watch():
             rows, cold = _timed(lambda: sim.sweep(**grid))
         acc = spy.accesses
-        print(f"{tag} {name} sweep: {len(rows)} rows in "
+        ran = _last_program()
+        want = {"mixed": "epoch program on reference",
+                "static": "static program on pallas"}[name]
+        _check(ran == want, f"{name} sweep: ran the {ran}, not the {want}")
+        print(f"{tag} {name} sweep ({ran}): {len(rows)} rows in "
               f"{sum(spy.rows.values())} device rows, {acc} simulated "
               f"accesses, cold {cold:.1f} s (compile included); "
               f"{_cache_events()}", flush=True)
@@ -241,16 +259,24 @@ def one_chip(dev) -> None:
               f"{_label(cpu)} ({secs:.1f} s on the chip, {cpu_secs:.1f} s "
               f"on the CPU)", flush=True)
 
+    static = _static_grid()
+    pal, pal_secs = _timed(lambda: sim.sweep(**static, backend="pallas"))
+    ref, ref_secs = _timed(lambda: sim.sweep(**static, backend="reference"))
+    _check(pal == ref, "static sweep: pallas rows differ from reference")
+    print(f"{tag} static sweep, backend='pallas' ({pal_secs:.1f} s): rows "
+          f"bitwise equal to backend='reference' ({ref_secs:.1f} s)",
+          flush=True)
     try:
-        sim.sweep((2,), backend="pallas")
+        sim.sweep((2,), tiering=(DynamicTiering(),), backend="pallas")
     except Exception as exc:  # the refusal is the expected outcome
         kind = resilience.classify_failure(exc)
         _check(kind == "fatal", f"a pallas refusal would be {kind}")
         first = str(exc).strip().splitlines()[0][:160]
-        print(f"{tag} backend='pallas' refused ({type(exc).__name__}, "
-              f"{kind}): {first}", flush=True)
+        print(f"{tag} dynamic-tiering sweep, backend='pallas' refused "
+              f"({type(exc).__name__}, {kind}): {first}", flush=True)
     else:
-        raise RuntimeError("backend='pallas' ran; update this check")
+        raise RuntimeError("backend='pallas' ran the epoch program; update "
+                           "this check")
     print(f"{tag} {_cache_events()}", flush=True)
 
 

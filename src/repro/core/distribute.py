@@ -164,29 +164,46 @@ def trace_working_set_bytes(b: int, n: int, fields: int = 4,
 # ---------------------------------------------------------------------------
 # pmap super-step: one shard per device, carry threaded between segments
 # ---------------------------------------------------------------------------
-@functools.lru_cache(maxsize=None)
-def _pmap_stepper(devices: Tuple, donate: bool):
-    """pmap of the engine's segment step, pinned to `devices`.
+def _kernel_segment(p: cache_mod.CacheParams, carry, addr: Array,
+                    is_write: Array, core: Array, tier: Array, *,
+                    chunk: int):
+    """The Pallas segment kernel with the reference step's signature."""
+    from repro.kernels import ops
+    return ops.mesi_run_segment(carry, addr, is_write, core, tier,
+                                params=p, chunk=chunk)
 
-    One cached instance per (devices, donate) pair: the mapped axis is
-    the super-step's shards, placed on exactly the mesh's devices (not
-    whatever `jax.local_devices()` order would pick), and the carry
-    buffers are donated between streamed segments off-CPU so only one
-    carry is ever resident per shard.
+
+@functools.lru_cache(maxsize=None)
+def _pmap_stepper(devices: Tuple, donate: bool, backend: str, chunk: int):
+    """pmap of one backend's segment step, pinned to `devices`.
+
+    One cached instance per (devices, donate, backend, chunk): the mapped
+    axis is the super-step's shards, placed on exactly the mesh's devices
+    (not whatever `jax.local_devices()` order would pick).  The reference
+    scan's carry buffers are donated between streamed segments off-CPU,
+    so only one carry is ever resident per shard; the kernel re-lays the
+    carry out into its planes, so it has no buffer to reuse.
     """
-    return jax.pmap(engine._run_batch_segment_impl,
-                    static_broadcasted_argnums=(0,),
+    if backend == "reference":
+        step = engine._run_batch_segment_impl
+    else:
+        step = functools.partial(_kernel_segment, chunk=chunk)
+        donate = False
+    return jax.pmap(step, static_broadcasted_argnums=(0,),
                     donate_argnums=(1,) if donate else (),
                     devices=devices)
 
 
 def _pmap_segment(p: cache_mod.CacheParams, devices: Tuple, carry,
-                  addr: Array, is_write: Array, core: Array, tier: Array):
+                  addr: Array, is_write: Array, core: Array, tier: Array,
+                  *, backend: str = "reference", chunk: int = 512):
     """Advance each device's shard by one trace segment (mapped axis =
-    shards of this super-step, one per entry of `devices`)."""
+    shards of this super-step, one per entry of `devices`) on the
+    resolved `backend`: the reference scan or the Pallas segment kernel,
+    which thread the same carry."""
     donate = jax.default_backend() != "cpu"
-    return _pmap_stepper(devices, donate)(p, carry, addr, is_write, core,
-                                          tier)
+    return _pmap_stepper(devices, donate, backend, chunk)(
+        p, carry, addr, is_write, core, tier)
 
 
 def _reshape_shards(x: Array, g: int) -> Array:
@@ -236,7 +253,7 @@ def stream_traces(p: cache_mod.CacheParams,
                   source: Iterable[Tuple], *,
                   checkpoint=None,
                   report: Optional[RunReport] = None,
-                  backend: str = "reference",
+                  backend: Optional[str] = None,
                   chunk: int = 512,
                   ) -> Tuple[Array, cache_mod.CacheState]:
     """Consume a trace as a stream of fixed-size segments, bounded memory.
@@ -263,10 +280,11 @@ def stream_traces(p: cache_mod.CacheParams,
         call and produces bitwise-identical results (test-enforced).
     report : RunReport, optional
         Event sink for ``resume`` / ``checkpoint`` records.
-    backend : {"reference", "pallas"}
+    backend : {"reference", "pallas", None}
         Segment stepper: the vmapped reference scan or the Pallas
         segment kernel — both thread the same ``(l1p, l2p, stats, t)``
-        carry and are bitwise-equal (test-enforced).
+        carry and are bitwise-equal (test-enforced).  ``None``: the
+        platform's (:func:`repro.core.engine.resolve_backend`).
     chunk : int
         Pallas kernel inner chunk length (ignored by the reference
         backend).
@@ -354,9 +372,6 @@ class ShardedExecutor:
     # -- static (flat-scan) rows -------------------------------------------
     def run_static(self, p: cache_mod.CacheParams, batch: TraceBatch,
                    *, backend: str, chunk: int) -> np.ndarray:
-        if backend != "reference":
-            return self._run_static_fallback(p, batch, backend=backend,
-                                             chunk=chunk)
         addr = jnp.asarray(batch.addr, jnp.int32)
         b, n = addr.shape
         z = jnp.zeros((b, n), jnp.int32)
@@ -393,42 +408,10 @@ class ShardedExecutor:
                 engine.init_batch_carry(p, g * bp))
             for s in range(0, n_pad, seg):
                 carry = _pmap_segment(p, devices[:g], carry,
-                                      *(a[:, :, s:s + seg] for a in sh))
+                                      *(a[:, :, s:s + seg] for a in sh),
+                                      backend=backend, chunk=chunk)
             # stats only; enqueue without blocking — super-steps overlap
             outs.append(carry[2].reshape(g * bp, -1))
-        jax.block_until_ready(outs)
-        stats = np.concatenate([np.asarray(o) for o in outs], axis=0)
-        return stats[:b].astype(np.int64)
-
-    def _run_static_fallback(self, p, batch, *, backend, chunk):
-        """Non-reference backends: per-shard `run_traces` dispatches.
-
-        ``stream_chunk`` routes each shard through the kernel's segment
-        path (``run_traces(segment=...)`` threads the packed carry
-        between fixed-size segments), so bounded-memory streaming works
-        identically on every backend — bitwise-equal to the resident
-        run (test-enforced)."""
-        mesh = self.mesh or Mesh(n_shards=1)
-        b = batch.batch
-        n_shards = mesh.shard_count(b)
-        bp, b_pad = shard_plan(b, n_shards)
-        addr = _pad_rows(jnp.asarray(batch.addr, jnp.int32), b_pad,
-                         SENTINEL)
-        z = jnp.zeros(addr.shape, jnp.int32)
-        others = [z if a is None else _pad_rows(jnp.asarray(a, jnp.int32),
-                                                b_pad, 0)
-                  for a in (batch.is_write, batch.core, batch.tier)]
-        devices = mesh.resolve_devices()
-        outs = []
-        for i, s0 in enumerate(range(0, b_pad, bp)):
-            rows = slice(s0, s0 + bp)
-            dev = devices[i % len(devices)]    # round-robin shard placement
-            args = [jax.device_put(a[rows], dev)
-                    for a in (addr, *others)]
-            stats, _ = engine.run_traces(p, *args, backend=backend,
-                                         chunk=chunk,
-                                         segment=self.stream_chunk)
-            outs.append(stats)
         jax.block_until_ready(outs)
         stats = np.concatenate([np.asarray(o) for o in outs], axis=0)
         return stats[:b].astype(np.int64)

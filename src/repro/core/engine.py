@@ -34,7 +34,7 @@ Backends
 ``pallas``
     :func:`repro.kernels.ops.mesi_cache_sim` — the full two-level MESI +
     tier state machine with VMEM-resident tags, a (batch, chunks) grid and
-    chunked HBM->VMEM trace streaming.  First-class across the whole sweep
+    chunked HBM->SMEM trace streaming.  First-class across the whole sweep
     matrix: the carry-exposing segment kernels
     (:func:`repro.kernels.ops.mesi_run_segment`,
     :func:`repro.kernels.ops.mesi_dyn_segment`) drive dynamic tiering,
@@ -42,6 +42,11 @@ Backends
     bitwise parity to the reference (test-enforced by
     tests/test_backend_parity.py).  Compiled on TPU backends; interpret
     mode elsewhere (parity validation — keep geometries small).
+
+``backend=None``, the default everywhere, resolves by platform
+(:func:`resolve_backend`): the static program runs the compiled kernel
+on a TPU and the reference scan elsewhere; the epoch program runs the
+reference scan, since Mosaic does not lower its kernel yet.
 """
 from __future__ import annotations
 
@@ -72,6 +77,34 @@ SENTINEL = cache_mod.SENTINEL   # padded trace entries: addr == SENTINEL
 BACKENDS = ("reference", "pallas")
 
 
+def resolve_backend(backend: Optional[str],
+                    params: Optional[cache_mod.CacheParams] = None, *,
+                    epoch: bool = False) -> str:
+    """The implementation a program runs: ``backend`` when given, else
+    the platform's.
+
+    ``None`` resolves to the compiled Pallas kernel for the static
+    program where jitted calls run on a TPU (the default device's
+    platform) and the state of one batch row fits the kernel's VMEM
+    (:func:`repro.kernels.cache_sim.vmem_bytes` of ``params``, when
+    given), and to the reference scan on any other platform, for a
+    larger cache, and for the epoch program (``epoch=True``), whose
+    kernel Mosaic does not lower yet.  An explicit name is only checked:
+    ``'reference'`` always runs the scan, and ``'pallas'`` where it
+    cannot compile raises.
+    """
+    if backend is None:
+        if epoch:
+            return "reference"
+        from repro.kernels import cache_sim, ops
+        fits = (params is None
+                or cache_sim.vmem_bytes(params) <= cache_sim.VMEM_LIMIT)
+        return "pallas" if ops.platform() == "tpu" and fits else "reference"
+    if backend not in BACKENDS:
+        raise ValueError(f"unknown backend {backend!r}; pick from {BACKENDS}")
+    return backend
+
+
 # ---------------------------------------------------------------------------
 # Sweep specification
 # ---------------------------------------------------------------------------
@@ -96,8 +129,9 @@ class SweepSpec:
     kernel : str
         STREAM kernel of the default workload axis (legacy knob; only used
         when `workloads` is empty).
-    backend : str
-        ``'reference'`` (vmapped scan) or ``'pallas'`` (MESI kernel).
+    backend : str or None
+        ``'reference'`` (vmapped scan), ``'pallas'`` (MESI kernel) or
+        ``None``: each program's platform default (:func:`resolve_backend`).
     topologies : tuple of route.TopologySpec
         Scenario axis #1: each spec is enumerated (committed HDM decoders)
         and its N-target route map drives per-access routing — e.g. one
@@ -144,7 +178,7 @@ class SweepSpec:
     policies: Tuple[numa_mod.Policy, ...] = (numa_mod.ZNuma(1.0),)
     cpus: Tuple[CPUModel, ...] = (CPUModel(kind="o3"),)
     kernel: str = "triad"
-    backend: str = "reference"
+    backend: Optional[str] = None
     topologies: Tuple[route_mod.TopologySpec, ...] = ()
     workloads: Tuple["Workload", ...] = ()
     tiering: Tuple[Optional[tiering_dyn.DynamicTiering], ...] = ()
@@ -375,7 +409,7 @@ def _segment_stepper(donate: bool):
 
 def run_batch_segment(p: cache_mod.CacheParams, carry, addr, is_write,
                       core, tier, *, donate: bool = False,
-                      backend: str = "reference", chunk: int = 512):
+                      backend: Optional[str] = None, chunk: int = 512):
     """One streamed segment: `(carry, (B, n_seg) slice) -> carry`.
 
     Parameters
@@ -390,10 +424,11 @@ def run_batch_segment(p: cache_mod.CacheParams, carry, addr, is_write,
     donate : bool
         Donate the carry buffers to the call (streaming loops off-CPU);
         the caller must not reuse the donated carry afterwards.
-    backend : str
-        'reference' (vmapped scan segment) or 'pallas'
-        (:func:`repro.kernels.ops.mesi_run_segment`).  Both thread the
-        identical carry, so segments may alternate backends freely with
+    backend : str or None
+        'reference' (vmapped scan segment), 'pallas'
+        (:func:`repro.kernels.ops.mesi_run_segment`) or None, the
+        platform's (:func:`resolve_backend`).  Both thread the identical
+        carry, so segments may alternate backends freely with
         bitwise-equal results (test-enforced).
     chunk : int
         Trace elements per Pallas grid step (pallas backend only).
@@ -403,12 +438,10 @@ def run_batch_segment(p: cache_mod.CacheParams, carry, addr, is_write,
     tuple
         The advanced carry; `carry[2]` is the running (B, nstats) stats.
     """
-    if backend == "pallas":
+    if resolve_backend(backend, p) == "pallas":
         from repro.kernels import ops
         return ops.mesi_run_segment(carry, addr, is_write, core, tier,
                                     params=p, chunk=chunk)
-    if backend != "reference":
-        raise ValueError(f"unknown backend {backend!r}; pick from {BACKENDS}")
     donate = donate and jax.default_backend() != "cpu"
     return _segment_stepper(donate)(p, carry, addr, is_write, core, tier)
 
@@ -430,7 +463,7 @@ def _run_batch_reference(p: cache_mod.CacheParams, addr: Array,
 
 
 def run_traces(p: cache_mod.CacheParams, addr, is_write,
-               core=None, tier=None, *, backend: str = "reference",
+               core=None, tier=None, *, backend: Optional[str] = None,
                chunk: int = 512, segment: Optional[int] = None,
                ) -> Tuple[Array, cache_mod.CacheState]:
     """Simulate a (B, N) batch of sentinel-padded traces in one device call.
@@ -440,7 +473,8 @@ def run_traces(p: cache_mod.CacheParams, addr, is_write,
         layout; per-config *traces/tiers/policies* are what vary).
       addr: (B, N) int32, `SENTINEL` marks padding.
       is_write/core/tier: (B, N) int32 (or None for zeros).
-      backend: 'reference' (vmapped scan) or 'pallas' (MESI kernel).
+      backend: 'reference' (vmapped scan), 'pallas' (MESI kernel) or
+        None, the platform's (:func:`resolve_backend`).
       chunk: trace elements per Pallas grid step.
       segment: stream the trace through the scan carry in (B, segment)
         slices — one device call per slice instead of one program over
@@ -456,9 +490,7 @@ def run_traces(p: cache_mod.CacheParams, addr, is_write,
         if addr.ndim != 2:
             raise ValueError("run_traces expects a (B, N) batch; "
                              "use addr[None] for a single trace")
-        if backend not in BACKENDS:
-            raise ValueError(
-                f"unknown backend {backend!r}; pick from {BACKENDS}")
+        backend = resolve_backend(backend, p)
         z = jnp.zeros(addr.shape, jnp.int32)
         is_write = z if is_write is None else jnp.asarray(is_write,
                                                           jnp.int32)
@@ -478,8 +510,8 @@ def run_traces(p: cache_mod.CacheParams, addr, is_write,
                                      params=p, chunk=chunk)
         sp.ready(out)
         if sp:
-            sp.add(program="static", row_steps=addr.shape[0] * addr.shape[1],
-                   segments=1)
+            sp.add(program="static", backend=backend,
+                   row_steps=addr.shape[0] * addr.shape[1], segments=1)
     return out
 
 
@@ -494,7 +526,7 @@ def _pad_to_segment(x: Array, n_to: int, fill: int) -> Array:
 
 def _run_traces_segmented(p: cache_mod.CacheParams, addr: Array,
                           is_write: Array, core: Array, tier: Array,
-                          *, segment: int, backend: str = "reference",
+                          *, segment: int, backend: str,
                           chunk: int = 512
                           ) -> Tuple[Array, cache_mod.CacheState]:
     """Host loop threading the scan carry through fixed-size segments.
@@ -526,7 +558,7 @@ def _run_traces_segmented(p: cache_mod.CacheParams, addr: Array,
                 chunk=chunk)
         sp.ready(carry)
         if sp:
-            sp.add(program="static", row_steps=b * n_pad,
+            sp.add(program="static", backend=backend, row_steps=b * n_pad,
                    segments=n_pad // segment)
     l1p, l2p, stats, _ = carry
     return stats, cache_mod.unpack_state(l1p, l2p)
@@ -836,19 +868,20 @@ def sweep_results(spec: SweepSpec, cache: cache_mod.CacheParams,
         workload, footprint, policy, cpu.
     """
     with obs.span("sweep") as sweep_span:
-        if spec.backend not in BACKENDS:
-            raise ValueError(f"unknown backend {spec.backend!r}")
+        epoch = (any(tr is not None for tr in spec.tiering_axis)
+                 or any(sp is not None for sp in spec.sampling_axis))
+        backend = resolve_backend(spec.backend, cache, epoch=epoch)
         executor = _resolve_executor(executor, resume, fault_plan, report)
         executor = executor if executor is not None else _LOCAL_EXECUTOR
         routes = [None if tp is None else route_mod.build_route(tp, timing)
                   for tp in spec.topology_axis]
-        if (any(tr is not None for tr in spec.tiering_axis)
-                or any(sp is not None for sp in spec.sampling_axis)):
+        if epoch:
             out = _sweep_results_dynamic(spec, cache, timing, routes,
-                                         executor=executor)
+                                         backend=backend, executor=executor)
         else:
             out = _sweep_results_static(spec, cache, timing, routes,
-                                        chunk=chunk, executor=executor)
+                                        backend=backend, chunk=chunk,
+                                        executor=executor)
         if sweep_span:
             sweep_span.add(rows=len(out))
     return out
@@ -857,13 +890,14 @@ def sweep_results(spec: SweepSpec, cache: cache_mod.CacheParams,
 def _sweep_results_static(spec: SweepSpec, cache: cache_mod.CacheParams,
                           timing: TimingConfig,
                           routes: Sequence[Optional[route_mod.RouteMap]],
-                          *, chunk: int, executor) -> List[RunResult]:
+                          *, backend: str, chunk: int, executor
+                          ) -> List[RunResult]:
     """The static-program body of `sweep_results`."""
     t_max = max(2 if r is None else r.n_targets for r in routes)
     p = dataclasses.replace(cache, n_targets=t_max)
     batch, cell_rows = build_sweep_batch(spec, cache, chunk=chunk,
                                          routes=routes)
-    stats = executor.run_static(p, batch, backend=spec.backend, chunk=chunk)
+    stats = executor.run_static(p, batch, backend=backend, chunk=chunk)
     cells = spec.sim_cells
     n_cells = len(cells)
     rows_cpus = [wl.cpu_for(cpu) for wl, _k, _pol in cells
@@ -1066,7 +1100,7 @@ def _build_tiering_batch(spec, cache, routes, slot, t_max):
 def _sweep_results_dynamic(spec: SweepSpec, cache: cache_mod.CacheParams,
                            timing: TimingConfig,
                            routes: Sequence[Optional[route_mod.RouteMap]],
-                           *, executor) -> List[RunResult]:
+                           *, backend: str, executor) -> List[RunResult]:
     """The epoch-structured twin of the static `sweep_results` body.
 
     One `tiering_dyn.run_dynamic` device call simulates every
@@ -1103,7 +1137,7 @@ def _sweep_results_dynamic(spec: SweepSpec, cache: cache_mod.CacheParams,
                 f"sweep's epoch gcd {slot}")
     tb = build_tiering_batch(spec, cache, routes, slot, t_max)
     out = executor.run_dynamic(p, tb, slot_len=slot, k_max=k_max,
-                               backend=spec.backend)
+                               backend=backend)
     stats = np.asarray(jax.block_until_ready(out.stats), np.int64)
     mig = np.stack([np.asarray(out.mig_read, np.int64),
                     np.asarray(out.mig_write, np.int64)], axis=1)

@@ -17,14 +17,17 @@ trace``            those builders                             ``accesses``
                    ``engine.run_traces`` and
                    ``tiering_dyn.prep_dynamic_inputs``
 ``sweep.program``  dispatch to completion of the device       ``program``,
-                   program in ``engine.run_traces`` and       ``row_steps``,
-                   ``tiering_dyn.run_dynamic``                ``segments``
+                   program in ``engine.run_traces`` and       ``backend``,
+                   ``tiering_dyn.run_dynamic``                ``row_steps``,
+                                                              ``segments``
 ``sweep.timing``   ``machine.time_batch``                     ``rows``
 ================== ========================================== ==============
 
 ``rows`` and ``steps`` are the batch rows and padded scan steps, and
 ``row_steps`` their product as the program ran it (segment padding
-included); ``accesses`` are unpadded trace entries.  Every counter comes
+included); ``accesses`` are unpadded trace entries.  ``program`` is
+``static`` or ``epoch`` and ``backend`` the implementation that ran it
+(``pallas`` or ``reference``, as ``engine.resolve_backend`` chose).  Every counter comes
 from shapes the host already knows, never from a device read.
 
 Each span records its name, its start and end on

@@ -92,7 +92,7 @@ class CXLRAMSim:
                      policy: Optional[numa_mod.Policy] = None,
                      kernel: str = "triad",
                      cpu: Optional[CPUModel] = None,
-                     backend: str = "reference",
+                     backend: Optional[str] = None,
                      topologies: Optional[Sequence[
                          route_mod.TopologySpec]] = None) -> List[Dict]:
         """The paper's §IV sweep: STREAM at k x L2 footprints.
@@ -112,7 +112,7 @@ class CXLRAMSim:
               policies: Optional[Sequence[numa_mod.Policy]] = None,
               cpus: Optional[Sequence[CPUModel]] = None,
               kernel: str = "triad",
-              backend: str = "reference",
+              backend: Optional[str] = None,
               topologies: Optional[Sequence[route_mod.TopologySpec]] = None,
               workloads: Optional[Sequence] = None,
               tiering: Optional[Sequence] = None,

@@ -663,7 +663,8 @@ def run_dynamic(p: cache_mod.CacheParams, addr, is_write, core, tier,
                                          segment_slots, backend)
         sp.ready(out)
         if sp:
-            sp.add(program="epoch", row_steps=b * e * slot_len,
+            sp.add(program="epoch", backend=backend,
+                   row_steps=b * e * slot_len,
                    segments=(1 if segment_slots is None
                              else -(-e // segment_slots)))
     return out

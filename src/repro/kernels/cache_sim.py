@@ -17,10 +17,12 @@ kernels live here:
 
 The TPU-native design shared by both:
 
-  * **state lives in VMEM scratch** — int32 arrays, <=1 MiB for realistic
-    geometries, persistent across the sequential TPU grid;
-  * the **trace streams HBM -> VMEM in chunks** via the BlockSpec index_map,
-    one grid step per chunk (double-buffered by the Pallas pipeline);
+  * **state lives in VMEM** — int32 arrays, persistent across the
+    sequential TPU grid; the MESI kernels keep it in lane-dense planes
+    (:class:`Layout`, 688 KiB at the paper's Table-I host);
+  * the **trace streams from HBM in chunks** via the BlockSpec index_map,
+    one grid step per chunk (double-buffered by the Pallas pipeline); the
+    MESI kernels read it as scalars from SMEM;
   * within a chunk the state machine is a `fori_loop` (trace order is a true
     dependency), but each iteration's tag compare / LRU victim select /
     directory probe is a vectorized op across `ways` lanes;
@@ -28,6 +30,10 @@ The TPU-native design shared by both:
     stacks B configurations and the kernel re-initializes its VMEM state at
     each row's first chunk, so a whole multi-config sweep is one kernel
     launch.
+
+`mesi_cache_sim` and `mesi_segment` compile for a TPU v5e
+(`tests/test_chip_compile.py`) and are what the static program runs
+there by default; Mosaic still refuses `mesi_dyn_segment`'s trace blocks.
 
 Sentinel padding convention
 ---------------------------
@@ -46,6 +52,7 @@ sweeps in interpret mode; `interpret=False` is the TPU target).
 """
 from __future__ import annotations
 
+import dataclasses
 import functools
 from typing import Tuple
 
@@ -173,17 +180,172 @@ def cache_sim(addr: Array, *, n_sets: int, n_ways: int,
 # ---------------------------------------------------------------------------
 # Full two-level MESI + tier kernel (batched engine backend)
 # ---------------------------------------------------------------------------
-def _mesi_access(l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh, stats,
-                 a_raw, w_i, c, tr, t, stat_gate, *, cores: int,
-                 l1_sets: int, l2_sets: int, n_targets: int):
-    """One MESI access against the VMEM-resident scratch state.
+#: Lanes of the kernels' stats row: counter ``k`` of
+#: :func:`repro.core.cache.stat_names` accumulates in lane ``k``.
+STAT_LANES = 128
+#: XLA lays a 1-D int32 array out on a TPU in tiles of this many elements;
+#: a compiled kernel's trace block is a whole number of tiles.
+TRACE_TILE = 1024
+_NO_LANE = 1 << 30                   # above every lane index: "no match"
+_INT_MAX = 2 ** 31 - 1
 
-    The shared per-access body of every MESI kernel in this module.  L1
-    state is flattened to (cores * l1_sets, l1_ways) so every row access
-    is a 2-D dynamic-slice; the per-core directory probes unroll over the
-    (static, small) `cores` dimension.  The update sequence mirrors
-    `repro.core.cache._step` operation-for-operation, so stats and final
-    state are bitwise-identical to the scan reference.
+
+def _sets_per_row(sets: int, width: int) -> int:
+    """Whole sets laid side by side in one plane row, up to 128 lanes."""
+    g = 1
+    while 2 * g <= sets and 2 * g * width <= 128:
+        g *= 2
+    return g
+
+
+@dataclasses.dataclass(frozen=True)
+class Layout:
+    """Where the MESI kernels keep each cache line in their VMEM planes.
+
+    Eight int32 planes hold the state, one field each: L1 tag, use and
+    MESI state, then L2 tag, use, state, tier and sharers.  An L1 plane
+    row holds ``g1`` whole sets side by side, each set as every core's
+    ways (lane ``(set % g1) * cores * l1_ways + core * l1_ways + way``);
+    an L2 plane row holds ``g2`` sets of ``l2_ways`` lanes.  ``g1`` and
+    ``g2`` fill a row up to 128 lanes, so at the Table-I geometry the
+    planes are (32, 128) and (256, 128): lane-dense, with no padding,
+    and one row load reads every core's copies of a set.  The same lane
+    of two planes is the same line, so a row's tag, use and state
+    vectors line up with no shuffle.
+    """
+    cores: int
+    l1_sets: int
+    l1_ways: int
+    l2_sets: int
+    l2_ways: int
+
+    @classmethod
+    def of(cls, p: CacheParams) -> "Layout":
+        return cls(p.cores, p.l1_sets, p.l1_ways, p.l2_sets, p.l2_ways)
+
+    @property
+    def cw(self) -> int:
+        """Lanes of one L1 set: every core's ways."""
+        return self.cores * self.l1_ways
+
+    @property
+    def g1(self) -> int:
+        return _sets_per_row(self.l1_sets, self.cw)
+
+    @property
+    def g2(self) -> int:
+        return _sets_per_row(self.l2_sets, self.l2_ways)
+
+    @property
+    def shape1(self) -> Tuple[int, int]:
+        return (self.l1_sets // self.g1, self.g1 * self.cw)
+
+    @property
+    def shape2(self) -> Tuple[int, int]:
+        return (self.l2_sets // self.g2, self.g2 * self.l2_ways)
+
+
+#: Mosaic's default scoped-VMEM limit on a TPU v5e: what one kernel's
+#: blocks and scratch may hold, since no call here raises it.
+VMEM_LIMIT = 16 * 2 ** 20
+
+
+def _tiled_bytes(shape: Tuple[int, int]) -> int:
+    """VMEM of a 2-D int32 block, rows and lanes padded to (8, 128)."""
+    return 4 * (-(-shape[0] // 8) * 8) * (-(-shape[1] // 128) * 128)
+
+
+def vmem_bytes(params: CacheParams) -> int:
+    """VMEM that :func:`mesi_segment`'s blocks take at this geometry.
+
+    The stats row and the 8 state planes are output blocks and, again,
+    carry-in blocks, and the pipeline double-buffers each: 4 copies of
+    one row's state.  :func:`mesi_cache_sim` has no carry-in, so it takes
+    half.  2.7 MiB at the paper's Table-I host; an L2 of 16 MiB or more
+    (16-way) does not fit :data:`VMEM_LIMIT`.
+    """
+    lay = Layout.of(params)
+    row = (_tiled_bytes((1, STAT_LANES)) + 3 * _tiled_bytes(lay.shape1)
+           + 5 * _tiled_bytes(lay.shape2))
+    return 4 * row
+
+
+def _carry_planes(lay: Layout, l1p: Array, l2p: Array):
+    """Split the engine's packed carry into the kernels' 8 state planes.
+
+    ``l1p`` is (B, cores, s1, w1, 3) [tag, use, state] and ``l2p`` is
+    (B, s2, w2, 5) [tag, use, state, tier, sharers]; each plane comes out
+    (B, *lay.shape1) or (B, *lay.shape2).
+    """
+    b = l1p.shape[0]
+    l1 = [jnp.swapaxes(l1p[..., k], 1, 2).reshape((b,) + lay.shape1)
+          for k in range(3)]
+    l2 = [l2p[..., k].reshape((b,) + lay.shape2) for k in range(5)]
+    return [x.astype(jnp.int32) for x in l1 + l2]
+
+
+def _l1_field(lay: Layout, plane: Array) -> Array:
+    """(B, *lay.shape1) plane -> (B, cores, s1, w1)."""
+    b = plane.shape[0]
+    return jnp.swapaxes(
+        plane.reshape(b, lay.l1_sets, lay.cores, lay.l1_ways), 1, 2)
+
+
+def _state_of(lay: Layout, planes) -> CacheState:
+    """The 8 planes as a batched CacheState."""
+    b = planes[0].shape[0]
+    l1t, l1u, l1s = (_l1_field(lay, x) for x in planes[:3])
+    l2t, l2u, l2s, l2tier, l2sh = (
+        x.reshape(b, lay.l2_sets, lay.l2_ways) for x in planes[3:])
+    return CacheState(l1_tag=l1t, l1_use=l1u, l1_state=l1s, l2_tag=l2t,
+                      l2_use=l2u, l2_state=l2s, l2_tier=l2tier,
+                      l2_sharers=l2sh)
+
+
+def _pack_planes(lay: Layout, planes):
+    """Inverse of :func:`_carry_planes`: 8 planes -> (l1p, l2p)."""
+    st = _state_of(lay, planes)
+    l1p = jnp.stack([st.l1_tag, st.l1_use, st.l1_state], axis=-1)
+    l2p = jnp.stack([st.l2_tag, st.l2_use, st.l2_state, st.l2_tier,
+                     st.l2_sharers], axis=-1)
+    return l1p, l2p
+
+
+def _first_lane(mask: Array, lane: Array) -> Array:
+    """Lowest lane of a (1, L) row where ``mask`` holds, as (1, 1);
+    ``_NO_LANE`` where none does.  The first-index tie rule of
+    ``jnp.argmax``/``jnp.argmin``, as a min that Mosaic lowers."""
+    return jnp.min(jnp.where(mask, lane, _NO_LANE), axis=1, keepdims=True)
+
+
+def _pick(sel: Array, row: Array) -> Array:
+    """The value of a (1, L) row in the one lane ``sel`` marks, as (1, 1)."""
+    return jnp.sum(jnp.where(sel, row, 0), axis=1, keepdims=True)
+
+
+def _row(ref, q):
+    return ref[pl.ds(q, 1), :]
+
+
+def _mesi_access(planes, stats: Array, a_raw, w_i, c, tr, t, stat_gate,
+                 *, lay: Layout, n_targets: int) -> Array:
+    """One MESI access against the VMEM-resident state planes.
+
+    The shared per-access body of every MESI kernel in this module.
+    ``planes`` are the eight refs of :class:`Layout`; the trace entry
+    (``a_raw``, ``w_i``, ``c``, ``tr``) and the clock ``t`` are scalars.
+    Returns ``stats``, the (1, STAT_LANES) counter row, advanced.  The
+    update sequence mirrors `repro.core.cache._step` operation for
+    operation, so stats and final state are bitwise-identical to the
+    scan reference.
+
+    Written for Mosaic: a lookup loads one plane row and masks its set's
+    lanes; a way is chosen by a lane-index min (:func:`_first_lane`), a
+    way's field read by a masked sum (:func:`_pick`), and a write is a
+    masked store of the whole row.  The only scalar taken from vector
+    data is the L2 set of the L1 victim's writeback.  The L2 victim's L1
+    copies are found by one compare over the whole L1 plane (4,096 tags
+    at Table I: four vregs a plane), which needs no index.
 
     ``stat_gate`` multiplies every stat increment (1 = measure, 0 =
     functional warming: the state machine still runs full fidelity, only
@@ -191,179 +353,187 @@ def _mesi_access(l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh, stats,
     :mod:`repro.core.sampling`; state writes are gated only on trace
     validity, exactly like the reference.
     """
+    l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh = planes
+    cw, w1, w2, g1, g2 = lay.cw, lay.l1_ways, lay.l2_ways, lay.g1, lay.g2
+    sh1, sh2 = g1.bit_length() - 1, g2.bit_length() - 1
     w = w_i != 0
     valid = a_raw >= 0                    # sentinel padding gate
     vi = valid.astype(jnp.int32) * stat_gate
     a = jnp.where(valid, a_raw, 0)
-    core_ids = jnp.arange(cores, dtype=jnp.int32)
-    mem_write = mem_write_base(n_targets)
-    upgrades, invalidations, back_invalidations, writebacks_l1 = (
-        coherence_base(n_targets) + k for k in range(4))
-
-    def bump(idx, amount):
-        stats[idx] = stats[idx] + amount.astype(jnp.int32) * vi
+    i32 = lambda x: x.astype(jnp.int32)
+    lane1 = jax.lax.broadcasted_iota(jnp.int32, (1, lay.shape1[1]), 1)
+    lane2 = jax.lax.broadcasted_iota(jnp.int32, (1, lay.shape2[1]), 1)
 
     # ---------------- L1 lookup ----------------
-    set1 = a & (l1_sets - 1)
-    r1 = c * l1_sets + set1
-    row_t = l1t[r1, :]                    # (l1_ways,) lanes
-    row_s = l1s[r1, :]
-    row_u = l1u[r1, :]
-    hits = (row_t == a) & (row_s != I)
-    l1_hit = hits.any()
-    way1 = jnp.where(l1_hit, jnp.argmax(hits),
-                     jnp.argmin(row_u)).astype(jnp.int32)
-    cur_state = row_s[way1]
+    set1 = a & (lay.l1_sets - 1)
+    q1, off1 = set1 >> sh1, (set1 & (g1 - 1)) * cw
+    own_lo = off1 + c * w1
+    row_t, row_u, row_s = _row(l1t, q1), _row(l1u, q1), _row(l1s, q1)
+    own = (lane1 >= own_lo) & (lane1 < own_lo + w1)
+    live = (row_t == a) & (row_s != I)
+    hit_lane = _first_lane(own & live, lane1)
+    l1_hit = hit_lane < _NO_LANE
+    umin = jnp.min(jnp.where(own, row_u, _INT_MAX), axis=1, keepdims=True)
+    sel1 = lane1 == jnp.where(
+        l1_hit, hit_lane, _first_lane(own & (row_u == umin), lane1))
+    cur_state = _pick(sel1, row_s)
+    evict_tag = _pick(sel1, row_t)
     needs_upgrade = l1_hit & w & (cur_state == S)
 
-    # directory-equivalent probe: all cores' copies of this line
-    copies_s = jnp.stack([l1s[k * l1_sets + set1, :]
-                          for k in range(cores)])       # (cores, ways)
-    copies_t = jnp.stack([l1t[k * l1_sets + set1, :]
-                          for k in range(cores)])
-    copies = (copies_t == a) & (copies_s != I)
-    other = copies & (core_ids[:, None] != c)
-    n_other = other.sum()
-
-    bump(L1_HIT, l1_hit)
-    bump(L1_MISS, ~l1_hit)
-    bump(upgrades, needs_upgrade)
-    bump(invalidations, jnp.where(w, n_other, 0))
-
+    # directory-equivalent probe: every other core's copy of this line
+    other = live & (lane1 >= off1) & (lane1 < off1 + cw) & ~own
+    n_other = jnp.sum(i32(other), axis=1, keepdims=True)
     # invalidate other copies on any write (upgrade or RFO fill)
-    inval = other & w & valid
-    for k in range(cores):
-        l1s[k * l1_sets + set1, :] = jnp.where(inval[k], I, copies_s[k])
+    l1s[pl.ds(q1, 1), :] = jnp.where(other & (w & valid), I, row_s)
 
     # ---------------- L1 victim writeback (on miss) ----------------
     evict_valid = (~l1_hit) & (cur_state != I)
-    evict_tag = row_t[way1]
     evict_dirty = evict_valid & (cur_state == M)
-    eset2 = evict_tag & (l2_sets - 1)
-    erow = l2t[eset2, :]
-    ehits = erow == evict_tag
-    ehit = ehits.any()
-    eway = jnp.where(ehit, jnp.argmax(ehits),
-                     jnp.argmin(l2u[eset2, :])).astype(jnp.int32)
-    # inclusive L2: mark dirty there on dirty eviction, drop the sharer
-    l2s[eset2, eway] = jnp.where(evict_dirty & ehit & valid,
-                                 M, l2s[eset2, eway])
-    l2sh[eset2, eway] = jnp.where(
-        evict_valid & ehit & valid,
-        l2sh[eset2, eway] & ~(jnp.int32(1) << c), l2sh[eset2, eway])
-    bump(writebacks_l1, evict_dirty)
+    eset2 = jnp.sum(jnp.where(sel1, row_t, 0)) & (lay.l2_sets - 1)
+    eq2, eoff = eset2 >> sh2, (eset2 & (g2 - 1)) * w2
+    eseg = (lane2 >= eoff) & (lane2 < eoff + w2)
+    # inclusive L2: mark dirty there on dirty eviction, drop the sharer;
+    # no lane is selected when the line is not in L2
+    esel = lane2 == _first_lane(eseg & (_row(l2t, eq2) == evict_tag), lane2)
+    erow_s, erow_sh = _row(l2s, eq2), _row(l2sh, eq2)
+    l2s[pl.ds(eq2, 1), :] = jnp.where(esel & evict_dirty & valid, M, erow_s)
+    l2sh[pl.ds(eq2, 1), :] = jnp.where(esel & evict_valid & valid,
+                                       erow_sh & ~(jnp.int32(1) << c),
+                                       erow_sh)
 
     # ---------------- L2 lookup (only meaningful on L1 miss) --------
-    set2 = a & (l2_sets - 1)
-    row2 = l2t[set2, :]
-    hits2 = row2 == a
-    l2_hit_raw = hits2.any()
-    way2 = jnp.where(l2_hit_raw, jnp.argmax(hits2),
-                     jnp.argmin(l2u[set2, :])).astype(jnp.int32)
+    set2 = a & (lay.l2_sets - 1)
+    q2, off2 = set2 >> sh2, (set2 & (g2 - 1)) * w2
+    row2_t, row2_u = _row(l2t, q2), _row(l2u, q2)
+    seg2 = (lane2 >= off2) & (lane2 < off2 + w2)
+    hit2_lane = _first_lane(seg2 & (row2_t == a), lane2)
+    l2_hit_raw = hit2_lane < _NO_LANE
+    umin2 = jnp.min(jnp.where(seg2, row2_u, _INT_MAX), axis=1,
+                    keepdims=True)
+    sel2 = lane2 == jnp.where(
+        l2_hit_raw, hit2_lane, _first_lane(seg2 & (row2_u == umin2), lane2))
     l2_hit = l2_hit_raw & (~l1_hit)
     l2_miss = (~l2_hit_raw) & (~l1_hit)
-    bump(L2_HIT, l2_hit)
-    bump(L2_MISS, l2_miss)
 
     # ---- L2 victim handling on fill: back-invalidate + writeback ----
-    v_tag = l2t[set2, way2]
-    v_state = l2s[set2, way2]
-    v_tier = l2tier[set2, way2]
+    row2_s, row2_tier, row2_sh = (_row(l2s, q2), _row(l2tier, q2),
+                                  _row(l2sh, q2))
+    v_tag = _pick(sel2, row2_t)
+    v_state = _pick(sel2, row2_s)
+    v_tier = _pick(sel2, row2_tier)
     v_valid = l2_miss & (v_state != I) & (v_tag != a)
-    vset1 = v_tag & (l1_sets - 1)
-    vc_s = jnp.stack([l1s[k * l1_sets + vset1, :]
-                      for k in range(cores)])
-    vc_t = jnp.stack([l1t[k * l1_sets + vset1, :]
-                      for k in range(cores)])
-    v_copies = (vc_t == v_tag) & (vc_s != I)
-    v_l1_dirty = (v_copies & (vc_s == M)).any()
-    for k in range(cores):
-        l1s[k * l1_sets + vset1, :] = jnp.where(
-            v_copies[k] & v_valid & valid, I, vc_s[k])
-    bump(back_invalidations, jnp.where(v_valid, v_copies.sum(), 0))
+    # the victim's L1 set, matched over the whole L1 plane
+    vset1 = v_tag & (lay.l1_sets - 1)
+    voff = (vset1 & (g1 - 1)) * cw
+    rows = jax.lax.broadcasted_iota(jnp.int32, lay.shape1, 0)
+    lanes = jax.lax.broadcasted_iota(jnp.int32, lay.shape1, 1)
+    all_t, all_s = l1t[...], l1s[...]
+    v_copies = ((rows == (vset1 >> sh1)) & (lanes >= voff)
+                & (lanes < voff + cw) & (all_t == v_tag) & (all_s != I))
+    v_l1_dirty = jnp.max(i32(v_copies & (all_s == M)), axis=(0, 1),
+                         keepdims=True) > 0
+    n_vcopies = jnp.sum(i32(v_copies), axis=(0, 1), keepdims=True)
+    l1s[...] = jnp.where(v_copies & v_valid & valid, I, all_s)
     v_dirty = v_valid & ((v_state == M) | v_l1_dirty)
-    # per-target attribution unrolls over the (static) target count
-    for tgt in range(n_targets):
-        bump(mem_write + tgt, v_dirty & (v_tier == tgt))
-
-    # ---- memory read on L2 miss ----
-    for tgt in range(n_targets):
-        bump(MEM_READ + tgt, l2_miss & (tr == tgt))
 
     # ---- install / update line in L2 ----
-    fill2 = l2_miss & valid
-    touch2 = (l2_hit | l2_miss) & valid
-    l2t[set2, way2] = jnp.where(fill2, a, l2t[set2, way2])
-    l2tier[set2, way2] = jnp.where(fill2, tr, l2tier[set2, way2])
-    l2s[set2, way2] = jnp.where(fill2, E, l2s[set2, way2])
-    l2u[set2, way2] = jnp.where(touch2, t, l2u[set2, way2])
+    fill2 = sel2 & l2_miss & valid
     me = jnp.int32(1) << c
-    l2sh[set2, way2] = jnp.where(
-        fill2, me,
-        jnp.where(l2_hit & valid, l2sh[set2, way2] | me,
-                  l2sh[set2, way2]))
+    q2s = pl.ds(q2, 1)
+    l2t[q2s, :] = jnp.where(fill2, a, row2_t)
+    l2tier[q2s, :] = jnp.where(fill2, tr, row2_tier)
+    l2s[q2s, :] = jnp.where(fill2, E, row2_s)
+    l2u[q2s, :] = jnp.where(sel2 & (l2_hit | l2_miss) & valid, t, row2_u)
+    l2sh[q2s, :] = jnp.where(
+        fill2, me, jnp.where(sel2 & l2_hit & valid, row2_sh | me, row2_sh))
 
     # ---------------- install / update line in L1 ----------------
     sole = n_other == 0
-    fill_state = jnp.where(w, M, jnp.where(sole, E, S)).astype(jnp.int32)
-    hit_state = jnp.where(w, M, cur_state).astype(jnp.int32)
+    fill_state = jnp.where(w, M, jnp.where(sole, E, S))
+    hit_state = jnp.where(w, M, cur_state)
     new_state = jnp.where(l1_hit, hit_state, fill_state)
-    l1t[r1, way1] = jnp.where(valid, a, l1t[r1, way1])
-    l1s[r1, way1] = jnp.where(valid, new_state, l1s[r1, way1])
-    l1u[r1, way1] = jnp.where(valid, t, l1u[r1, way1])
+    put1 = sel1 & valid
+    q1s = pl.ds(q1, 1)
+    l1t[q1s, :] = jnp.where(put1, a, row_t)
+    l1u[q1s, :] = jnp.where(put1, t, row_u)
+    l1s[q1s, :] = jnp.where(put1, new_state, _row(l1s, q1))
+
+    # ---- stats: one row add, counter k in lane k ----
+    sl = jax.lax.broadcasted_iota(jnp.int32, (1, STAT_LANES), 1)
+    upg, inval, binval, wb1 = (coherence_base(n_targets) + k
+                               for k in range(4))
+    incs = ((L1_HIT, i32(l1_hit)), (L1_MISS, i32(~l1_hit)),
+            (L2_HIT, i32(l2_hit)), (L2_MISS, i32(l2_miss)),
+            (MEM_READ + tr, i32(l2_miss)),
+            (mem_write_base(n_targets) + v_tier, i32(v_dirty)),
+            (upg, i32(needs_upgrade)),
+            (inval, jnp.where(w, n_other, 0)),
+            (binval, jnp.where(v_valid, n_vcopies, 0)),
+            (wb1, i32(evict_dirty)))
+    inc = sum(jnp.where(sl == k, amount, 0) for k, amount in incs)
+    return stats + inc * vi
 
 
-def _mesi_kernel(addr_ref, w_ref, core_ref, tier_ref,
-                 stats_ref, l1t_ref, l1u_ref, l1s_ref,
-                 l2t_ref, l2u_ref, l2s_ref, l2tier_ref, l2sh_ref,
-                 l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh, stats,
-                 *, chunk: int, cores: int, l1_sets: int, l1_ways: int,
-                 l2_sets: int, l2_ways: int, n_chunks: int,
-                 n_targets: int):
-    """One (batch-row, chunk) grid step of the two-level MESI state machine.
+def _run_chunk(trace, planes, stats, base_t, *, block: int, lay: Layout,
+               n_targets: int) -> None:
+    """Run one block of a row's accesses: a ``fori_loop`` over the SMEM
+    trace block, the stats row carried in registers."""
+    addr_ref, w_ref, core_ref, tier_ref = trace
 
-    The per-access body is the shared :func:`_mesi_access`; this kernel
-    owns the fresh-state initialization and the end-of-row publish.
+    def body(i, acc):
+        return _mesi_access(planes, acc, addr_ref[i], w_ref[i], core_ref[i],
+                            tier_ref[i], base_t + i, jnp.int32(1), lay=lay,
+                            n_targets=n_targets)
+
+    stats[...] = jax.lax.fori_loop(0, block, body, stats[...])
+
+
+def _mesi_kernel(addr_ref, w_ref, core_ref, tier_ref, stats, *planes,
+                 block: int, lay: Layout, n_targets: int):
+    """One (batch-row, block) grid step of the two-level MESI state machine.
+
+    The state lives in the output blocks: their index depends on the row
+    only, so they stay in VMEM across the row's blocks and are written
+    back once, after its last.  This kernel owns the fresh-state
+    initialization; the per-access body is :func:`_mesi_access`.
     """
     j = pl.program_id(1)
 
-    # fresh state at the first chunk of every batch row
     @pl.when(j == 0)
     def _init():
-        l1t[...] = jnp.full((cores * l1_sets, l1_ways), -1, jnp.int32)
-        l1u[...] = jnp.zeros((cores * l1_sets, l1_ways), jnp.int32)
-        l1s[...] = jnp.zeros((cores * l1_sets, l1_ways), jnp.int32)
-        l2t[...] = jnp.full((l2_sets, l2_ways), -1, jnp.int32)
-        l2u[...] = jnp.zeros((l2_sets, l2_ways), jnp.int32)
-        l2s[...] = jnp.zeros((l2_sets, l2_ways), jnp.int32)
-        l2tier[...] = jnp.zeros((l2_sets, l2_ways), jnp.int32)
-        l2sh[...] = jnp.zeros((l2_sets, l2_ways), jnp.int32)
-        stats[...] = jnp.zeros((nstats(n_targets),), jnp.int32)
+        for ref, fill in zip(planes, (-1, 0, 0, -1, 0, 0, 0, 0)):
+            ref[...] = jnp.full(ref.shape, fill, jnp.int32)
+        stats[...] = jnp.zeros(stats.shape, jnp.int32)
 
-    base_t = j * chunk + 1
+    _run_chunk((addr_ref, w_ref, core_ref, tier_ref), planes, stats,
+               j * block + 1, block=block, lay=lay, n_targets=n_targets)
 
-    def body(i, carry):
-        _mesi_access(l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh, stats,
-                     addr_ref[0, i], w_ref[0, i], core_ref[0, i],
-                     tier_ref[0, i], base_t + i, jnp.int32(1),
-                     cores=cores, l1_sets=l1_sets, l2_sets=l2_sets,
-                     n_targets=n_targets)
-        return carry
 
-    jax.lax.fori_loop(0, chunk, body, 0)
+def _trace_blocks(chunk: int, interpret: bool, addr, *fields):
+    """Pad a (B, N) trace batch to whole blocks and flatten it for SMEM.
 
-    # publish this batch row's stats + final state after its last chunk
-    @pl.when(j == n_chunks - 1)
-    def _out():
-        stats_ref[0, :] = stats[...]
-        l1t_ref[0] = l1t[...]
-        l1u_ref[0] = l1u[...]
-        l1s_ref[0] = l1s[...]
-        l2t_ref[0] = l2t[...]
-        l2u_ref[0] = l2u[...]
-        l2s_ref[0] = l2s[...]
-        l2tier_ref[0] = l2tier[...]
-        l2sh_ref[0] = l2sh[...]
+    The loop reads every trace field as a scalar, so a block is a 1-D
+    SMEM window of one row's trace: ``chunk`` entries, rounded up to
+    whole :data:`TRACE_TILE` tiles when compiled.  Returns the flat
+    fields, their block spec, the block and the blocks per row.
+    """
+    block = chunk if interpret else -(-chunk // TRACE_TILE) * TRACE_TILE
+    padded = pad_trace(block, addr, *fields)
+    b, n = padded[0].shape
+    n_blocks = n // block
+    flat = [x.astype(jnp.int32).reshape(b * n) for x in padded]
+    spec = pl.BlockSpec((block,), lambda b_, j: (b_ * n_blocks + j,),
+                        memory_space=pltpu.SMEM)
+    return flat, spec, block, n_blocks
+
+
+def _state_specs(b: int, lay: Layout):
+    """Block specs and shapes of the stats row and the 8 state planes."""
+    def spec(shape):
+        return pl.BlockSpec((None,) + shape, lambda b_, j: (b_, 0, 0))
+    shapes = [(1, STAT_LANES)] + [lay.shape1] * 3 + [lay.shape2] * 5
+    return ([spec(s) for s in shapes],
+            [jax.ShapeDtypeStruct((b,) + s, jnp.int32) for s in shapes])
 
 
 @functools.partial(jax.jit,
@@ -374,20 +544,24 @@ def mesi_cache_sim(addr: Array, is_write: Array, core: Array, tier: Array,
                    ) -> Tuple[Array, CacheState]:
     """Two-level MESI + tier simulation of a (B, N) trace batch.
 
-    The grid is (B, n_chunks): chunks stream sequentially per batch row and
-    the VMEM-resident state re-initializes at each row's first chunk, so a
-    whole multi-configuration sweep is a single kernel launch.
+    The grid is (B, n_blocks): blocks stream sequentially per batch row
+    and the VMEM-resident state re-initializes at each row's first block,
+    so a whole multi-configuration sweep is a single kernel launch.
 
-    VMEM budget per row: ``4 B * (3 * cores * l1_sets * l1_ways +
-    5 * l2_sets * l2_ways)`` for state plus two ``4 * chunk`` trace tiles —
-    ~0.7 MiB for the paper's Table-I host (4 cores, 64 KiB L1, 2 MiB L2).
+    VMEM: the state planes of one row, ``4 B * (3 * cores * l1_sets *
+    l1_ways + 5 * l2_sets * l2_ways)`` (688 KiB at the paper's Table-I
+    host: 4 cores, 64 KiB L1, 2 MiB L2; the planes are lane-dense, so
+    nothing pads), held in the output blocks, which the pipeline
+    double-buffers: 1.34 MiB.  The trace blocks live
+    in SMEM (4 fields x 1,024 entries x 4 B, double-buffered: 32 KiB).
 
     Args:
       addr: (B, N) int32 line addresses; `SENTINEL` (-1) marks padding
-        (appended automatically if N is not a multiple of `chunk`).
+        (appended automatically up to a whole block).
       is_write/core/tier: (B, N) int32.
       params: cache geometry (static).
-      chunk: trace elements per grid step.
+      chunk: trace elements per grid step (rounded up to whole
+        :data:`TRACE_TILE` tiles when compiled).
       interpret: interpret mode (CPU validation; TPU target is False).
 
     Returns: (stats (B, nstats(params.n_targets)) int32, batched
@@ -397,136 +571,49 @@ def mesi_cache_sim(addr: Array, is_write: Array, core: Array, tier: Array,
     if addr.ndim != 2:
         raise ValueError("mesi_cache_sim expects a (B, N) batch")
     b = addr.shape[0]
-    addr, is_write, core, tier = pad_trace(chunk, addr, is_write, core, tier)
-    n = addr.shape[1]
-    n_chunks = n // chunk
-    cores, s1, w1 = params.cores, params.l1_sets, params.l1_ways
-    s2, w2 = params.l2_sets, params.l2_ways
-    ns = nstats(params.n_targets)
-
-    kernel = functools.partial(
-        _mesi_kernel, chunk=chunk, cores=cores, l1_sets=s1, l1_ways=w1,
-        l2_sets=s2, l2_ways=w2, n_chunks=n_chunks,
-        n_targets=params.n_targets)
-    trace_spec = pl.BlockSpec((1, chunk), lambda b_, j: (b_, j))
-    state_specs = [
-        pl.BlockSpec((1, ns), lambda b_, j: (b_, 0)),
-        pl.BlockSpec((1, cores * s1, w1), lambda b_, j: (b_, 0, 0)),
-        pl.BlockSpec((1, cores * s1, w1), lambda b_, j: (b_, 0, 0)),
-        pl.BlockSpec((1, cores * s1, w1), lambda b_, j: (b_, 0, 0)),
-    ] + [pl.BlockSpec((1, s2, w2), lambda b_, j: (b_, 0, 0))] * 5
-    state_shapes = [
-        jax.ShapeDtypeStruct((b, ns), jnp.int32),
-        jax.ShapeDtypeStruct((b, cores * s1, w1), jnp.int32),
-        jax.ShapeDtypeStruct((b, cores * s1, w1), jnp.int32),
-        jax.ShapeDtypeStruct((b, cores * s1, w1), jnp.int32),
-    ] + [jax.ShapeDtypeStruct((b, s2, w2), jnp.int32)] * 5
-    scratch = [pltpu.VMEM((cores * s1, w1), jnp.int32)] * 3 \
-        + [pltpu.VMEM((s2, w2), jnp.int32)] * 5 \
-        + [pltpu.VMEM((ns,), jnp.int32)]
-
-    outs = pl.pallas_call(
+    lay = Layout.of(params)
+    trace, trace_spec, block, n_blocks = _trace_blocks(
+        chunk, interpret, addr, is_write, core, tier)
+    out_specs, out_shape = _state_specs(b, lay)
+    kernel = functools.partial(_mesi_kernel, block=block, lay=lay,
+                               n_targets=params.n_targets)
+    stats, *planes = pl.pallas_call(
         kernel,
-        grid=(b, n_chunks),
+        grid=(b, n_blocks),
         in_specs=[trace_spec] * 4,
-        out_specs=state_specs,
-        out_shape=state_shapes,
-        scratch_shapes=scratch,
+        out_specs=out_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(addr.astype(jnp.int32), is_write.astype(jnp.int32),
-      core.astype(jnp.int32), tier.astype(jnp.int32))
-
-    stats, l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh = outs
-    shape1 = (b, cores, s1, w1)
-    state = CacheState(
-        l1_tag=l1t.reshape(shape1), l1_use=l1u.reshape(shape1),
-        l1_state=l1s.reshape(shape1), l2_tag=l2t, l2_use=l2u,
-        l2_state=l2s, l2_tier=l2tier, l2_sharers=l2sh)
-    return stats, state
+    )(*trace)
+    return stats[:, 0, :nstats(params.n_targets)], _state_of(lay, planes)
 
 
 # ---------------------------------------------------------------------------
 # Carry-in / carry-out segment kernel (streaming + checkpoint/resume)
 # ---------------------------------------------------------------------------
-def _carry_planes(l1p: Array, l2p: Array):
-    """Split the engine's packed carry into the kernel's 8 state planes.
-
-    ``l1p`` is (B, cores, s1, w1, 3) [tag, use, state] and ``l2p`` is
-    (B, s2, w2, 5) [tag, use, state, tier, sharers]; the kernel wants the
-    flattened (B, cores * s1, w1) / (B, s2, w2) per-plane layout of
-    :func:`mesi_cache_sim`.
-    """
-    b, cores, s1, w1 = l1p.shape[:4]
-    sh1 = (b, cores * s1, w1)
-    return ([l1p[..., k].reshape(sh1) for k in range(3)]
-            + [l2p[..., k] for k in range(5)])
-
-
-def _pack_planes(planes, b: int, cores: int, s1: int, w1: int):
-    """Inverse of :func:`_carry_planes`: 8 planes -> (l1p, l2p)."""
-    l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh = planes
-    sh4 = (b, cores, s1, w1)
-    l1p = jnp.stack([x.reshape(sh4) for x in (l1t, l1u, l1s)], axis=-1)
-    l2p = jnp.stack([l2t, l2u, l2s, l2tier, l2sh], axis=-1)
-    return l1p, l2p
-
-
 def _mesi_segment_kernel(addr_ref, w_ref, core_ref, tier_ref, t0_ref,
-                         l1t_in, l1u_in, l1s_in, l2t_in, l2u_in, l2s_in,
-                         l2tier_in, l2sh_in, stats_in,
-                         stats_ref, l1t_ref, l1u_ref, l1s_ref,
-                         l2t_ref, l2u_ref, l2s_ref, l2tier_ref, l2sh_ref,
-                         l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh, stats,
-                         *, chunk: int, cores: int, l1_sets: int,
-                         l1_ways: int, l2_sets: int, l2_ways: int,
-                         n_chunks: int, n_targets: int):
+                         stats_in, *refs, block: int, lay: Layout,
+                         n_targets: int):
     """Segment variant of :func:`_mesi_kernel`: state flows carry->carry.
 
-    Instead of zero-initializing at each row's first chunk, the incoming
-    packed carry (state planes + stats + logical clock t0) seeds the VMEM
-    scratch, so a trace split into segments threads identical arithmetic
+    Instead of a fresh state at each row's first block, the incoming
+    carry (state planes, stats, logical clock t0) seeds the output
+    blocks, so a trace split into segments threads identical arithmetic
     through the carry — the resumable-stream contract of
     :func:`repro.core.engine.run_batch_segment`.
     """
+    planes_in, (stats, *planes) = refs[:8], refs[8:]
     j = pl.program_id(1)
 
-    # seed persistent state from the incoming carry at each row's first chunk
     @pl.when(j == 0)
     def _init():
-        l1t[...] = l1t_in[0]
-        l1u[...] = l1u_in[0]
-        l1s[...] = l1s_in[0]
-        l2t[...] = l2t_in[0]
-        l2u[...] = l2u_in[0]
-        l2s[...] = l2s_in[0]
-        l2tier[...] = l2tier_in[0]
-        l2sh[...] = l2sh_in[0]
-        stats[...] = stats_in[0]
+        for dst, src in zip(planes, planes_in):
+            dst[...] = src[...]
+        stats[...] = stats_in[...]
 
-    base_t = t0_ref[0, 0] + j * chunk
-
-    def body(i, carry):
-        _mesi_access(l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh, stats,
-                     addr_ref[0, i], w_ref[0, i], core_ref[0, i],
-                     tier_ref[0, i], base_t + i, jnp.int32(1),
-                     cores=cores, l1_sets=l1_sets, l2_sets=l2_sets,
-                     n_targets=n_targets)
-        return carry
-
-    jax.lax.fori_loop(0, chunk, body, 0)
-
-    # publish this batch row's stats + final state after its last chunk
-    @pl.when(j == n_chunks - 1)
-    def _out():
-        stats_ref[0, :] = stats[...]
-        l1t_ref[0] = l1t[...]
-        l1u_ref[0] = l1u[...]
-        l1s_ref[0] = l1s[...]
-        l2t_ref[0] = l2t[...]
-        l2u_ref[0] = l2u[...]
-        l2s_ref[0] = l2s[...]
-        l2tier_ref[0] = l2tier[...]
-        l2sh_ref[0] = l2sh[...]
+    _run_chunk((addr_ref, w_ref, core_ref, tier_ref), planes, stats,
+               t0_ref[pl.program_id(0)] + j * block, block=block, lay=lay,
+               n_targets=n_targets)
 
 
 @functools.partial(jax.jit,
@@ -540,17 +627,19 @@ def mesi_segment(carry, addr: Array, is_write: Array, core: Array,
     ``(l1p, l2p, stats, t)`` tuple — what the reference
     ``run_batch_segment`` threads between segments and what checkpoint/
     resume snapshots — so segments may alternate freely between this
-    kernel and the reference scan with bitwise-identical results.
+    kernel and the reference scan with bitwise-identical results.  VMEM
+    as :func:`mesi_cache_sim`, plus the double-buffered carry-in planes.
 
     Args:
       carry: ``(l1p, l2p, stats, t)`` packed batch carry (leading B).
       addr: (B, N) int32 line addresses; any N — sentinel-padded to a
-        multiple of `chunk` internally.  Padded entries never touch
-        state, and the returned clock advances by the *unpadded* N, so
-        internal chunk padding is invisible in the carry.
+        whole block internally.  Padded entries never touch state, and
+        the returned clock advances by the *unpadded* N, so internal
+        padding is invisible in the carry.
       is_write/core/tier: (B, N) int32.
       params: cache geometry (static).
-      chunk: trace elements per grid step.
+      chunk: trace elements per grid step (rounded up to whole
+        :data:`TRACE_TILE` tiles when compiled).
       interpret: interpret mode (CPU validation; TPU target is False).
 
     Returns: the advanced ``(l1p, l2p, stats, t)`` carry.
@@ -559,47 +648,27 @@ def mesi_segment(carry, addr: Array, is_write: Array, core: Array,
     if addr.ndim != 2:
         raise ValueError("mesi_segment expects a (B, N) batch")
     b, n = addr.shape
-    addr, is_write, core, tier = pad_trace(chunk, addr, is_write, core, tier)
-    n_chunks = addr.shape[1] // chunk
-    cores, s1, w1 = params.cores, params.l1_sets, params.l1_ways
-    s2, w2 = params.l2_sets, params.l2_ways
     ns = nstats(params.n_targets)
-
-    kernel = functools.partial(
-        _mesi_segment_kernel, chunk=chunk, cores=cores, l1_sets=s1,
-        l1_ways=w1, l2_sets=s2, l2_ways=w2, n_chunks=n_chunks,
-        n_targets=params.n_targets)
-    trace_spec = pl.BlockSpec((1, chunk), lambda b_, j: (b_, j))
-    t_spec = pl.BlockSpec((1, 1), lambda b_, j: (b_, 0))
-    st_spec = pl.BlockSpec((1, ns), lambda b_, j: (b_, 0))
-    l1_spec = pl.BlockSpec((1, cores * s1, w1), lambda b_, j: (b_, 0, 0))
-    l2_spec = pl.BlockSpec((1, s2, w2), lambda b_, j: (b_, 0, 0))
-    state_shapes = [
-        jax.ShapeDtypeStruct((b, ns), jnp.int32),
-    ] + [jax.ShapeDtypeStruct((b, cores * s1, w1), jnp.int32)] * 3 \
-        + [jax.ShapeDtypeStruct((b, s2, w2), jnp.int32)] * 5
-    scratch = [pltpu.VMEM((cores * s1, w1), jnp.int32)] * 3 \
-        + [pltpu.VMEM((s2, w2), jnp.int32)] * 5 \
-        + [pltpu.VMEM((ns,), jnp.int32)]
-
-    planes = _carry_planes(l1p, l2p)
-    t0 = t.astype(jnp.int32).reshape(b, 1)
+    lay = Layout.of(params)
+    trace, trace_spec, block, n_blocks = _trace_blocks(
+        chunk, interpret, addr, is_write, core, tier)
+    state_specs, out_shape = _state_specs(b, lay)
+    kernel = functools.partial(_mesi_segment_kernel, block=block, lay=lay,
+                               n_targets=params.n_targets)
+    stats_in = jnp.zeros((b, 1, STAT_LANES), jnp.int32).at[:, 0, :ns].set(
+        jnp.asarray(stats, jnp.int32))
     outs = pl.pallas_call(
         kernel,
-        grid=(b, n_chunks),
-        in_specs=[trace_spec] * 4 + [t_spec]
-        + [l1_spec] * 3 + [l2_spec] * 5 + [st_spec],
-        out_specs=[st_spec] + [l1_spec] * 3 + [l2_spec] * 5,
-        out_shape=state_shapes,
-        scratch_shapes=scratch,
+        grid=(b, n_blocks),
+        in_specs=[trace_spec] * 4 + [pl.BlockSpec(memory_space=pltpu.SMEM)]
+        + state_specs,
+        out_specs=state_specs,
+        out_shape=out_shape,
         interpret=interpret,
-    )(addr.astype(jnp.int32), is_write.astype(jnp.int32),
-      core.astype(jnp.int32), tier.astype(jnp.int32), t0,
-      *planes, jnp.asarray(stats, jnp.int32))
-
-    stats_o = outs[0]
-    l1p_o, l2p_o = _pack_planes(outs[1:], b, cores, s1, w1)
-    return (l1p_o, l2p_o, stats_o, t + jnp.int32(n))
+    )(*trace, t.astype(jnp.int32).reshape(b), stats_in,
+      *_carry_planes(lay, l1p, l2p))
+    l1p_o, l2p_o = _pack_planes(lay, outs[1:])
+    return (l1p_o, l2p_o, outs[0][:, 0, :ns], t + jnp.int32(n))
 
 
 # ---------------------------------------------------------------------------
@@ -624,9 +693,8 @@ def _mesi_dyn_kernel(addr_ref, w_ref, core_ref, tier_ref, sc_ref, ptl_ref,
                      slots_ref, snaps_ref, meas_ref,
                      l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh, stats,
                      pmap_s, counts_s, migr_s, migw_s,
-                     *, slot_len: int, cores: int, l1_sets: int,
-                     l1_ways: int, l2_sets: int, l2_ways: int,
-                     n_slots: int, n_targets: int, n_p: int, k_max: int,
+                     *, slot_len: int, lay: Layout, n_slots: int,
+                     n_targets: int, n_p: int, k_max: int,
                      count_bound: int):
     """One (batch-row, epoch-slot) grid step of the dynamic tierer.
 
@@ -643,6 +711,7 @@ def _mesi_dyn_kernel(addr_ref, w_ref, core_ref, tier_ref, sc_ref, ptl_ref,
     because stat updates are integer adds.
     """
     j = pl.program_id(1)
+    ns = nstats(n_targets)
 
     # seed the full tierer carry from the inputs at each row's first slot
     @pl.when(j == 0)
@@ -655,7 +724,8 @@ def _mesi_dyn_kernel(addr_ref, w_ref, core_ref, tier_ref, sc_ref, ptl_ref,
         l2s[...] = l2s_in[0]
         l2tier[...] = l2tier_in[0]
         l2sh[...] = l2sh_in[0]
-        stats[...] = stats_in[0]
+        stats[...] = jnp.zeros(stats.shape, jnp.int32)
+        stats[:, :ns] = stats_in[...]
         pmap_s[...] = pmap_in[0]
         counts_s[...] = counts_in[0]
         migr_s[...] = migr_in[0]
@@ -683,8 +753,10 @@ def _mesi_dyn_kernel(addr_ref, w_ref, core_ref, tier_ref, sc_ref, ptl_ref,
     meas = jnp.where(s_p > 0, (pos >= s_w) & (pos < s_w + s_m),
                      True).astype(jnp.int32)
 
+    planes = (l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh)
+
     def body(i, acc):
-        acc_t, acc_d = acc
+        acc_t, acc_d, acc_s = acc
         a_raw = addr_ref[0, 0, i]
         v = (a_raw >= 0).astype(jnp.int32)
         page = jnp.clip(a_raw // lpp, 0, n_p - 1)
@@ -697,16 +769,15 @@ def _mesi_dyn_kernel(addr_ref, w_ref, core_ref, tier_ref, sc_ref, ptl_ref,
                         jnp.where(intent == 0, 0,
                                   jnp.where(intent >= 2, ssd_t, tr_s)),
                         tr_s)
-        _mesi_access(l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh, stats,
-                     a_raw, w_ref[0, 0, i], core_ref[0, 0, i], tgt,
-                     base_t + i, meas, cores=cores, l1_sets=l1_sets,
-                     l2_sets=l2_sets, n_targets=n_targets)
+        acc_s = _mesi_access(planes, acc_s, a_raw, w_ref[0, 0, i],
+                             core_ref[0, 0, i], tgt, base_t + i, meas,
+                             lay=lay, n_targets=n_targets)
         counts_s[page] = counts_s[page] + v
         sel = jnp.where(flag != 0, intent, tgt)
-        return acc_t + v, acc_d + v * (sel == 0).astype(jnp.int32)
+        return acc_t + v, acc_d + v * (sel == 0).astype(jnp.int32), acc_s
 
-    acc_t, acc_d = jax.lax.fori_loop(
-        0, slot_len, body, (jnp.int32(0), jnp.int32(0)))
+    acc_t, acc_d, stats[...] = jax.lax.fori_loop(
+        0, slot_len, body, (jnp.int32(0), jnp.int32(0), stats[...]))
 
     # ---- epoch-boundary promotion/demotion decision ----
     boundary = ((eidx + 1) % per) == 0
@@ -809,13 +880,13 @@ def _mesi_dyn_kernel(addr_ref, w_ref, core_ref, tier_ref, sc_ref, ptl_ref,
     # per-slot outputs (every slot publishes its own block)
     slots_ref[0, 0, :] = jnp.stack([acc_t, acc_d, n_pro + n_sup,
                                     n_dem + n_over])
-    snaps_ref[0, 0, :] = stats[...]
+    snaps_ref[0, 0, :] = stats[0, :ns]
     meas_ref[0, 0] = meas
 
     # publish this batch row's final carry after its last slot
     @pl.when(j == n_slots - 1)
     def _out():
-        stats_ref[0, :] = stats[...]
+        stats_ref[0, :] = stats[0, :ns]
         l1t_ref[0] = l1t[...]
         l1u_ref[0] = l1u[...]
         l1s_ref[0] = l1s[...]
@@ -860,8 +931,7 @@ def mesi_dyn_segment(carry, addr: Array, is_write: Array, core: Array,
     n_p = int(page_target_lines.shape[1])
     n_t = params.n_targets
     ns = nstats(n_t)
-    cores, s1, w1 = params.cores, params.l1_sets, params.l1_ways
-    s2, w2 = params.l2_sets, params.l2_ways
+    lay = Layout.of(params)
     # k_max is a static argname — int() runs at trace time, not on a
     # traced value  # repro-lint: disable=RL201
     k_max = min(int(k_max), n_p)
@@ -876,15 +946,15 @@ def mesi_dyn_segment(carry, addr: Array, is_write: Array, core: Array,
                     i32(t), i32(eidx)], axis=1)
 
     kernel = functools.partial(
-        _mesi_dyn_kernel, slot_len=slot_len, cores=cores, l1_sets=s1,
-        l1_ways=w1, l2_sets=s2, l2_ways=w2, n_slots=e, n_targets=n_t,
+        _mesi_dyn_kernel, slot_len=slot_len, lay=lay, n_slots=e,
+        n_targets=n_t,
         n_p=n_p, k_max=k_max, count_bound=count_bound)
     trace_spec = pl.BlockSpec((1, 1, slot_len), lambda b_, j: (b_, j, 0))
     sc_spec = pl.BlockSpec((1, len(DYN_SCALARS)), lambda b_, j: (b_, 0))
     ptl_spec = pl.BlockSpec((1, n_p, n_t), lambda b_, j: (b_, 0, 0))
     st_spec = pl.BlockSpec((1, ns), lambda b_, j: (b_, 0))
-    l1_spec = pl.BlockSpec((1, cores * s1, w1), lambda b_, j: (b_, 0, 0))
-    l2_spec = pl.BlockSpec((1, s2, w2), lambda b_, j: (b_, 0, 0))
+    l1_spec = pl.BlockSpec((1,) + lay.shape1, lambda b_, j: (b_, 0, 0))
+    l2_spec = pl.BlockSpec((1,) + lay.shape2, lambda b_, j: (b_, 0, 0))
     pg_spec = pl.BlockSpec((1, n_p), lambda b_, j: (b_, 0))
     tg_spec = pl.BlockSpec((1, n_t), lambda b_, j: (b_, 0))
     slots_spec = pl.BlockSpec((1, 1, 4), lambda b_, j: (b_, j, 0))
@@ -894,20 +964,20 @@ def mesi_dyn_segment(carry, addr: Array, is_write: Array, core: Array,
         + [pg_spec] * 2 + [tg_spec] * 2
     out_shape = [
         jax.ShapeDtypeStruct((b, ns), jnp.int32),
-    ] + [jax.ShapeDtypeStruct((b, cores * s1, w1), jnp.int32)] * 3 \
-        + [jax.ShapeDtypeStruct((b, s2, w2), jnp.int32)] * 5 \
+    ] + [jax.ShapeDtypeStruct((b,) + lay.shape1, jnp.int32)] * 3 \
+        + [jax.ShapeDtypeStruct((b,) + lay.shape2, jnp.int32)] * 5 \
         + [jax.ShapeDtypeStruct((b, n_p), jnp.int32)] * 2 \
         + [jax.ShapeDtypeStruct((b, n_t), jnp.int32)] * 2 \
         + [jax.ShapeDtypeStruct((b, e, 4), jnp.int32),
            jax.ShapeDtypeStruct((b, e, ns), jnp.int32),
            jax.ShapeDtypeStruct((b, e), jnp.int32)]
-    scratch = [pltpu.VMEM((cores * s1, w1), jnp.int32)] * 3 \
-        + [pltpu.VMEM((s2, w2), jnp.int32)] * 5 \
-        + [pltpu.VMEM((ns,), jnp.int32)] \
+    scratch = [pltpu.VMEM(lay.shape1, jnp.int32)] * 3 \
+        + [pltpu.VMEM(lay.shape2, jnp.int32)] * 5 \
+        + [pltpu.VMEM((1, STAT_LANES), jnp.int32)] \
         + [pltpu.VMEM((n_p,), jnp.int32)] * 2 \
         + [pltpu.VMEM((n_t,), jnp.int32)] * 2
 
-    planes = _carry_planes(l1p, l2p)
+    planes = _carry_planes(lay, l1p, l2p)
     outs = pl.pallas_call(
         kernel,
         grid=(b, e),
@@ -923,7 +993,7 @@ def mesi_dyn_segment(carry, addr: Array, is_write: Array, core: Array,
       i32(counts), i32(mig_rd), i32(mig_wr))
 
     stats_o = outs[0]
-    l1p_o, l2p_o = _pack_planes(outs[1:9], b, cores, s1, w1)
+    l1p_o, l2p_o = _pack_planes(lay, outs[1:9])
     pmap_o, counts_o, migr_o, migw_o, slots, snaps, meas = outs[9:]
     new_carry = (l1p_o, l2p_o, stats_o, t + jnp.int32(e * slot_len),
                  pmap_o, counts_o, migr_o, migw_o,

@@ -1,8 +1,8 @@
 """Public jit'd wrappers over the Pallas kernels.
 
-Each op selects `interpret` mode from the default backend: compiled
-kernels on a TPU (v5e is the target), Python-interpreted bodies on the CPU
-(the tests), and an error on any other backend.  Model code calls these;
+Each op selects `interpret` mode from the platform (:func:`platform`):
+compiled kernels on a TPU (v5e is the target), Python-interpreted bodies
+on the CPU (the tests), and an error on any other backend.  Model code calls these;
 pure-JAX fallbacks (`*_jnp`) are what the multi-pod dry-run lowers, since
 Pallas TPU kernels cannot lower on the CPU host platform.
 """
@@ -25,9 +25,18 @@ from repro.kernels.stream_triad import stream_triad as _triad_kernel
 Array = jax.Array
 
 
+def platform() -> str:
+    """The platform jitted calls run on: the default device's when one is
+    set (``jax.default_device``), else the default backend."""
+    dev = jax.config.jax_default_device
+    if dev is None:
+        return jax.default_backend()
+    return dev if isinstance(dev, str) else dev.platform
+
+
 def _interpret() -> bool:
     """Interpret on the CPU, compile on a TPU; refuse any other backend."""
-    backend = jax.default_backend()
+    backend = platform()
     if backend not in ("cpu", "tpu"):
         raise RuntimeError(
             f"Pallas kernels run compiled on a TPU or interpreted on the "

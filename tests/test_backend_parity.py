@@ -10,6 +10,7 @@ freely and a checkpoint written by one resumes on the other.
 """
 import numpy as np
 import pytest
+from jax.tree_util import tree_map as jax_tree_map
 
 from repro.core import cache as C
 from repro.core import distribute, engine, numa
@@ -57,6 +58,55 @@ def spec(backend="reference", **kw):
                 backend=backend)
     base.update(kw)
     return engine.SweepSpec(**base)
+
+
+# ---------------------------------------------------------------------------
+# which implementation runs where
+# ---------------------------------------------------------------------------
+@pytest.mark.parametrize("platform,backend,epoch,want", [
+    ("cpu", None, False, "reference"),
+    ("cpu", None, True, "reference"),
+    ("tpu", None, False, "pallas"),     # the static program's kernel
+    ("tpu", None, True, "reference"),   # the epoch kernel does not lower
+    ("tpu", "reference", False, "reference"),
+    ("cpu", "pallas", True, "pallas"),
+])
+def test_backend_resolves_by_platform_and_program(monkeypatch, platform,
+                                                  backend, epoch, want):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "platform", lambda: platform)
+    assert engine.resolve_backend(backend, epoch=epoch) == want
+
+
+@pytest.mark.parametrize("l2_mib,want", [
+    (2, "pallas"),        # Table I: 2.7 MiB of kernel state blocks
+    (8, "pallas"),        # 10.2 MiB, the largest 16-way L2 that fits
+    (16, "reference"),    # 20.2 MiB, over Mosaic's 16 MiB scoped VMEM
+])
+def test_default_backend_keeps_the_scan_where_the_state_outgrows_vmem(
+        monkeypatch, l2_mib, want):
+    from repro.kernels import ops
+    monkeypatch.setattr(ops, "platform", lambda: "tpu")
+    p = C.CacheParams(cores=4, l2_bytes=l2_mib * 2 ** 20)
+    assert engine.resolve_backend(None, p) == want
+    assert engine.resolve_backend("pallas", p) == "pallas"
+
+
+def test_unknown_backend_is_refused():
+    with pytest.raises(ValueError, match="unknown backend"):
+        engine.resolve_backend("mosaic")
+    with pytest.raises(ValueError, match="unknown backend"):
+        engine.run_sweep(spec("mosaic"), CACHE, TIMING)
+
+
+def test_default_device_sets_the_platform():
+    import jax
+
+    from repro.kernels import ops
+    with jax.default_device(jax.devices("cpu")[0]):
+        assert ops.platform() == "cpu"
+    with jax.default_device("cpu"):
+        assert ops.platform() == "cpu"
 
 
 # ---------------------------------------------------------------------------
@@ -108,6 +158,87 @@ def test_segment_carry_interchangeable_between_backends():
             chunk=32)
     np.testing.assert_array_equal(np.asarray(carry[2]),
                                   np.asarray(ref[0]))
+
+
+# ---------------------------------------------------------------------------
+# the kernels' tie rules, one access at a time, against cache._step
+# ---------------------------------------------------------------------------
+# CACHE: per core an L1 of 16 sets x 2 ways, a shared L2 of 32 sets x 4
+# ways; lines 0, 32, 64, 96 and 128 share L1 set 0 and L2 set 0.
+def _equal_use_state():
+    """L1 set 1 of core 0 and L2 set 1 full of live lines, every way
+    last used at the same time: the LRU victim is the first way."""
+    st = C.init_state(CACHE)
+    l1 = st.l1_tag.at[0, 1].set(np.array([1, 17]))
+    l2 = st.l2_tag.at[1].set(np.array([1, 17, 33, 49]))
+    return st._replace(
+        l1_tag=l1, l1_use=st.l1_use.at[0, 1].set(5),
+        l1_state=st.l1_state.at[0, 1].set(np.array([C.E, C.S])),
+        l2_tag=l2, l2_use=st.l2_use.at[1].set(5),
+        l2_state=st.l2_state.at[1].set(C.E),
+        l2_sharers=st.l2_sharers.at[1].set(np.array([1, 1, 0, 0])))
+
+
+TIE_CASES = {
+    # core 1's write leaves an invalid way below a valid one in core 0's
+    # set; core 0 then misses into it, while the L2 set still has
+    # several invalid ways of equal use
+    "invalid_ways": (None, [(0, 0, 0), (16, 0, 0), (0, 1, 1), (32, 0, 0),
+                            (48, 0, 0), (16, 0, 1), (0, 0, 0)]),
+    # misses into full sets whose ways were all last used together
+    "equal_use": (_equal_use_state, [(65, 0, 0), (81, 1, 0), (1, 0, 1),
+                                     (97, 0, 1), (17, 1, 0)]),
+    # a shared line written by the core that holds it S (an upgrade),
+    # then by the other core (an RFO fill): each invalidates the other
+    # copy
+    "multicore_write": (None, [(5, 0, 0), (5, 0, 1), (5, 1, 1), (5, 1, 0),
+                               (5, 0, 1), (21, 1, 0), (37, 0, 0)]),
+    # core 0 holds line 0 dirty while core 1 pushes it out of L2: the
+    # back-invalidation finds a dirty L1 victim and writes it back
+    "dirty_back_invalidation": (None, [(0, 1, 0), (32, 0, 1), (64, 0, 1),
+                                       (96, 1, 1), (128, 0, 1),
+                                       (0, 0, 0)]),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TIE_CASES))
+def test_kernel_tie_rules_match_step(case):
+    from repro.kernels import ops
+    make_state, rows = TIE_CASES[case]
+    st0 = C.init_state(CACHE) if make_state is None else make_state()
+    addr, wr, core = (np.array([r[k] for r in rows], np.int32)
+                      for k in range(3))
+    tier = addr % CACHE.n_targets
+    want_st, want = C.simulate_trace(CACHE, st0, addr, wr.astype(bool),
+                                     core, tier)
+    want_run = (np.asarray(want)[None], jax_tree_map(
+        lambda x: np.asarray(x)[None], want_st))
+    trace = [x[None] for x in (addr, wr, core, tier)]
+    l1p, l2p = (x[None] for x in C.pack_state(st0))
+    stats0 = np.zeros((1, C.nstats(CACHE.n_targets)), np.int32)
+    t0 = np.ones((1,), np.int32)
+
+    if make_state is None:     # the fresh-state kernel starts from init
+        assert_run_equal(ops.mesi_cache_sim(*trace, params=CACHE, chunk=4),
+                         want_run)
+    l1s, l2s, stats, _ = ops.mesi_run_segment(
+        (l1p, l2p, stats0, t0), *trace, params=CACHE, chunk=4)
+    assert_run_equal((stats, C.unpack_state(l1s, l2s)), want_run)
+
+    # the epoch kernel, on a static row (its page map never routes)
+    one = np.ones((1,), np.int32)
+    zero = 0 * one
+    pages = 2
+    carry = (l1p, l2p, stats0, t0, np.zeros((1, pages), np.int32),
+             np.zeros((1, pages), np.int32),
+             np.zeros((1, CACHE.n_targets), np.int32),
+             np.zeros((1, CACHE.n_targets), np.int32), zero)
+    (l1d, l2d, stats_d, *_), *_ = ops.mesi_dyn_segment(
+        carry, *(x[:, None] for x in trace), zero, pages * one, zero, one,
+        one, pages * one, zero, pages * one,
+        np.zeros((1, pages, CACHE.n_targets), np.int32), zero, zero, zero,
+        params=CACHE, k_max=1, count_bound=len(rows) + 1)
+    assert_run_equal((stats_d, C.unpack_state(l1d, l2d)), want_run)
 
 
 # ---------------------------------------------------------------------------

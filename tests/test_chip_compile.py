@@ -8,11 +8,17 @@ nothing about results or speed.
 
 Geometry is the paper's Table-I host (4 cores, 64 KiB 8-way L1, 2 MiB
 16-way L2) with five route targets (``switched(4)``); batch and segment
-are cut so each program compiles in seconds.  The Pallas MESI kernels
-are not here: Mosaic refuses their (1, chunk) trace blocks, which break
-the 8x128 tiling rule.  One program too big for the chip's HBM checks
-that the compiler's refusal reads as an out-of-memory to the resilient
-executor, which then narrows the segment instead of giving up.
+are cut so each program compiles in seconds.  The static program's
+Pallas MESI kernels (``mesi_cache_sim``, ``mesi_segment``: what a TPU
+runs by default) compile with ``interpret=False`` at two and five
+targets and two batch widths, and the VMEM rule that keeps a larger
+cache on the reference scan matches what the compiler accepts.  The
+epoch program's kernel (``mesi_dyn_segment``) is not here: Mosaic still
+refuses its (1, 1, slot) trace blocks, which break the 8x128 tiling
+rule.  One
+program too big for the chip's HBM checks that the compiler's refusal
+reads as an out-of-memory to the resilient executor, which then narrows
+the segment instead of giving up.
 """
 import functools
 import os
@@ -24,6 +30,7 @@ from jax.sharding import SingleDeviceSharding
 
 from repro.core import cache as cache_mod
 from repro.core import engine, resilience, tiering_dyn
+from repro.kernels import cache_sim
 
 PARAMS = cache_mod.CacheParams(cores=4, n_targets=5)
 BATCH = 8
@@ -73,6 +80,49 @@ def test_static_segment_compiles(one_chip, no_compile_cache):
     compiled = engine._segment_stepper(True).lower(
         PARAMS, carry, *trace).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
+
+
+@pytest.mark.parametrize("batch", [2, 8])
+@pytest.mark.parametrize("n_targets", [2, 5])
+@pytest.mark.parametrize("kernel", ["mesi_cache_sim", "mesi_segment"])
+def test_static_kernel_compiles(one_chip, no_compile_cache, kernel,
+                                n_targets, batch):
+    """The static program's kernels lower through Mosaic and compile."""
+    p = cache_mod.CacheParams(cores=4, n_targets=n_targets)
+    trace = [jax.ShapeDtypeStruct((batch, 2 * SLOT), jnp.int32,
+                                  sharding=one_chip)] * 4
+    carry = ([_shapes(jax.eval_shape(functools.partial(
+        engine.init_batch_carry, p, batch)), one_chip)]
+        if kernel == "mesi_segment" else [])
+    compiled = getattr(cache_sim, kernel).lower(
+        *carry, *trace, params=p, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("l2_mib", [8, 16])
+def test_vmem_rule_matches_the_compiler(one_chip, no_compile_cache, l2_mib):
+    """The default backend runs the kernel only where one row's state
+    blocks fit Mosaic's scoped VMEM (``engine.resolve_backend``).  At a
+    wide batch, where XLA cannot hold whole operands in VMEM itself, the
+    largest 16-way L2 the rule admits compiles and the next size up runs
+    out of scoped VMEM, so the rule is neither too strict nor too lax."""
+    p = cache_mod.CacheParams(cores=4, n_targets=2,
+                              l2_bytes=l2_mib * 2 ** 20)
+    fits = cache_sim.vmem_bytes(p) <= cache_sim.VMEM_LIMIT
+    assert fits == (l2_mib == 8)
+    batch = 256
+    trace = [jax.ShapeDtypeStruct((batch, cache_sim.TRACE_TILE), jnp.int32,
+                                  sharding=one_chip)] * 4
+    carry = _shapes(jax.eval_shape(functools.partial(
+        engine.init_batch_carry, p, batch)), one_chip)
+    lowered = cache_sim.mesi_segment.lower(carry, *trace, params=p,
+                                           interpret=False)
+    if fits:
+        assert "tpu_custom_call" in lowered.compile().as_text()
+    else:
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="scoped vmem limit"):
+            lowered.compile()
 
 
 def test_dynamic_segment_compiles(one_chip, no_compile_cache):

@@ -211,6 +211,7 @@ def test_streaming_validation():
 
 
 _FOUR_DEVICE_SCRIPT = """
+import dataclasses
 import sys
 from collections import Counter
 
@@ -225,14 +226,15 @@ from repro.core.timing import TimingConfig
 assert len(jax.devices()) == 4
 cache = C.CacheParams(l1_bytes=8 * 1024, l1_ways=2, l2_bytes=16 * 1024,
                       l2_ways=8)
-program = sys.argv[1]
-# four static rows (the pmap path), or eight epoch-program rows (the
-# round-robin path): two real rows a device
+program, backend = sys.argv[1], sys.argv[2]
+# four static rows (the pmap path, on either backend), or eight
+# epoch-program rows (the round-robin path): two real rows a device
 spec = engine.SweepSpec(
     footprint_factors=(1, 2), policies=(numa.ZNuma(1.0),),
     topologies=(route_mod.direct(1), route_mod.direct(2)),
     tiering=(() if program == "static"
              else (None, DynamicTiering(epoch_len=512))))
+sharded_spec = dataclasses.replace(spec, backend=backend)
 live = Counter()       # device id -> rows with non-zero counters
 
 def record(stats):
@@ -252,7 +254,8 @@ def spy(*args, **kw):
     return out
 
 setattr(mod, name, spy)
-sharded = distribute.run_sweep(spec, cache, TimingConfig(), mesh=4)
+sharded = distribute.run_sweep(sharded_spec, cache, TimingConfig(),
+                               mesh=4)
 setattr(mod, name, fn)
 rows = 4 if program == "static" else 8
 assert dict(live) == {d: rows // 4 for d in range(4)}, dict(live)
@@ -260,18 +263,21 @@ assert sharded == engine.run_sweep(spec, cache, TimingConfig())
 """
 
 
-@pytest.mark.parametrize("program", ["static", "dynamic"])
-def test_four_devices_each_get_a_shard_with_parity(program):
-    """Static rows pmap over every device, epoch-program shards land on
-    every device round-robin and gather back on the host (a concatenate
-    across devices is refused); each device simulates real rows and the
-    rows equal the one-device sweep.  Needs its own process for four
-    virtual CPU devices."""
+@pytest.mark.parametrize("program,backend", [
+    ("static", "reference"), ("static", "pallas"), ("dynamic", "reference"),
+])
+def test_four_devices_each_get_a_shard_with_parity(program, backend):
+    """Static rows pmap over every device, on the reference scan or the
+    Pallas segment kernel, epoch-program shards land on every device
+    round-robin and gather back on the host (a concatenate across
+    devices is refused); each device simulates real rows and the rows
+    equal the one-device sweep on the reference scan.  Needs its own
+    process for four virtual CPU devices."""
     src = pathlib.Path(__file__).resolve().parents[1] / "src"
     env = {**os.environ, "JAX_PLATFORMS": "cpu", "PYTHONPATH": str(src),
            "XLA_FLAGS": "--xla_force_host_platform_device_count=4"}
     res = subprocess.run([sys.executable, "-c", _FOUR_DEVICE_SCRIPT,
-                          program],
+                          program, backend],
                          env=env, capture_output=True, text=True,
                          timeout=300)
     assert res.returncode == 0, res.stderr[-3000:]

@@ -120,6 +120,8 @@ def test_counters_are_the_batch_shapes(traced):
     assert program.counters["segments"] == 1
     assert program.counters["program"] == (
         "static" if kind == "static" else "epoch")
+    # the CPU resolves both programs to the reference scan
+    assert program.counters["backend"] == "reference"
     (sweep,) = _named(mine, "sweep")
     assert sum(r.counters["rows"] for r in _named(mine, "sweep.timing")) \
         == sweep.counters["rows"]
@@ -163,6 +165,17 @@ def test_segmented_programs_count_padding_and_segments(kind):
     assert program.sweep_id is None and program.parent_id is None
     assert program.counters["row_steps"] == want_steps
     assert program.counters["segments"] == want_segments
+
+
+@pytest.mark.parametrize("segment", [None, 256])
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_program_span_names_the_backend_that_ran(backend, segment):
+    rng = np.random.default_rng(6)
+    addr = jnp.asarray(rng.integers(0, 512, (2, 300)), jnp.int32)
+    _, recs = _recorded(lambda: engine.run_traces(
+        CACHE, addr, None, backend=backend, chunk=128, segment=segment))
+    (program,) = _named(recs, "sweep.program")
+    assert program.counters["backend"] == backend
 
 
 def test_off_records_nothing_opens_nothing_and_listens_to_nothing(

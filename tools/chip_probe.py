@@ -6,12 +6,15 @@
     python tools/chip_probe.py donation
 
 ``static`` times one warm call of the static segment program
-(``engine.run_batch_segment``) over 2,048 random accesses per row at the
-paper's Table-I geometry (4 cores, 64 KiB 8-way L1, 2 MiB 16-way L2,
-five route targets) for each batch width, and once at a small geometry
-(8 KiB 2-way L1, 16 KiB 8-way L2) at B=8, and prints microseconds per
-scan step.  ``dynamic`` does the same for one 4,096-access epoch slot of
-the epoch program (``tiering_dyn.run_dynamic_segment``, 1,026 pages).
+(``engine.run_batch_segment``) over 2,048 random accesses per row, from
+random cores, at the paper's Table-I geometry (4 cores, 64 KiB 8-way
+L1, 2 MiB 16-way L2, five route targets) for each batch width, and once
+at a small geometry (8 KiB 2-way L1, 16 KiB 8-way L2) at B=8, on each
+backend (the reference scan and the Pallas kernel); it prints
+microseconds per scan step and whether the two backends' carries are
+bitwise equal.  ``dynamic`` does the same for one 4,096-access epoch
+slot of the epoch program (``tiering_dyn.run_dynamic_segment``, 1,026
+pages), on the reference scan.
 ``donation`` says whether a segment call deletes its input carry with
 ``donate=False`` and with ``donate=True``.
 
@@ -40,16 +43,19 @@ def _timed(fn):
     return time.perf_counter() - t
 
 
-def _trace(rng, b, n, n_targets):
+def _trace(rng, b, n, n_targets, cores=1):
     import jax.numpy as jnp
     lines = 4 * 2 ** 20 // 64          # a 4 MiB footprint, 2 x L2
     return (jnp.asarray(rng.integers(0, lines, (b, n)), jnp.int32),
             jnp.asarray(rng.integers(0, 2, (b, n)), jnp.int32),
-            jnp.zeros((b, n), jnp.int32),
+            jnp.asarray(rng.integers(0, cores, (b, n)), jnp.int32),
             jnp.asarray(rng.integers(0, n_targets, (b, n)), jnp.int32))
 
 
 def static(tag, rng, batches) -> None:
+    import jax
+    import numpy as np
+
     from repro.core import cache as cache_mod
     from repro.core import engine
     table1 = cache_mod.CacheParams(cores=4, n_targets=5)
@@ -58,15 +64,21 @@ def static(tag, rng, batches) -> None:
                                   n_targets=5)
     for name, p, bs in (("Table I", table1, batches), ("small", small, (8,))):
         for b in bs:
-            trace = _trace(rng, b, STEPS, p.n_targets)
+            trace = _trace(rng, b, STEPS, p.n_targets, p.cores)
             carry = engine.init_batch_carry(p, b)
-
-            def call():
-                return engine.run_batch_segment(p, carry, *trace)
-            first, warm = _timed(call), _timed(call)
-            print(f"{tag} static segment, {name} geometry, B={b}: first "
-                  f"{first:.2f} s, warm {warm:.3f} s, "
-                  f"{warm / STEPS * 1e6:.1f} us per step", flush=True)
+            outs = []
+            for backend in engine.BACKENDS:
+                def call(backend=backend):
+                    return engine.run_batch_segment(p, carry, *trace,
+                                                    backend=backend)
+                first, warm = _timed(call), _timed(call)
+                outs.append(jax.device_get(call()))
+                print(f"{tag} static segment, {name} geometry, B={b}, "
+                      f"{backend}: first {first:.2f} s, warm {warm:.4f} s, "
+                      f"{warm / STEPS * 1e6:.3f} us per step", flush=True)
+            same = all(np.array_equal(x, y) for x, y in zip(*outs))
+            print(f"{tag} static segment, {name} geometry, B={b}: carries "
+                  f"bitwise equal across backends: {same}", flush=True)
 
 
 def dynamic(tag, rng, batches) -> None:
@@ -106,7 +118,8 @@ def donation(tag, rng, _batches) -> None:
     carry = engine.init_batch_carry(p, 8)
     for donate in (False, True):
         out = jax.block_until_ready(
-            engine.run_batch_segment(p, carry, *trace, donate=donate))
+            engine.run_batch_segment(p, carry, *trace, donate=donate,
+                                     backend="reference"))
         print(f"{tag} segment call with donate={donate}: input carry "
               f"deleted {[x.is_deleted() for x in carry]}", flush=True)
         carry = out
