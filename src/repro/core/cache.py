@@ -125,6 +125,13 @@ class CacheParams:
             raise ValueError(f"L2 sets must be a power of two, got {s}")
         return s
 
+    @property
+    def state_bytes(self) -> int:
+        """Bytes of one row's cache state: 3 int32 fields a line in the
+        L1s (tag, use, MESI state), 5 in the L2 (and tier, sharers)."""
+        return 4 * (3 * self.cores * self.l1_sets * self.l1_ways
+                    + 5 * self.l2_sets * self.l2_ways)
+
 
 class CacheState(NamedTuple):
     l1_tag: Array     # (cores, l1_sets, l1_ways) int32, -1 invalid

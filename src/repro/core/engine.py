@@ -85,8 +85,8 @@ def resolve_backend(backend: Optional[str],
 
     ``None`` resolves to the compiled Pallas kernel for the static
     program where jitted calls run on a TPU (the default device's
-    platform) and the state of one batch row fits the kernel's VMEM
-    (:func:`repro.kernels.cache_sim.vmem_bytes` of ``params``, when
+    platform) and the kernels' blocks for one batch row fit the chip's
+    VMEM (:func:`repro.kernels.cache_sim.fits_chip` of ``params``, when
     given), and to the reference scan on any other platform, for a
     larger cache, and for the epoch program (``epoch=True``), whose
     kernel Mosaic does not lower yet.  An explicit name is only checked:
@@ -97,9 +97,10 @@ def resolve_backend(backend: Optional[str],
         if epoch:
             return "reference"
         from repro.kernels import cache_sim, ops
-        fits = (params is None
-                or cache_sim.vmem_bytes(params) <= cache_sim.VMEM_LIMIT)
-        return "pallas" if ops.platform() == "tpu" and fits else "reference"
+        if ops.platform() != "tpu":
+            return "reference"
+        fits = params is None or cache_sim.fits_chip(params)
+        return "pallas" if fits else "reference"
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; pick from {BACKENDS}")
     return backend
@@ -511,8 +512,20 @@ def run_traces(p: cache_mod.CacheParams, addr, is_write,
         sp.ready(out)
         if sp:
             sp.add(program="static", backend=backend,
-                   row_steps=addr.shape[0] * addr.shape[1], segments=1)
+                   row_steps=addr.shape[0] * addr.shape[1], segments=1,
+                   **_state_counters(p, backend))
     return out
+
+
+def _state_counters(p: cache_mod.CacheParams, backend: str) -> Dict:
+    """``sweep.program``'s counters of the static program's state: one
+    row's cache state in bytes, and the scoped VMEM the kernel compiled
+    with (0 on the scan)."""
+    limit = 0
+    if backend == "pallas":
+        from repro.kernels import cache_sim
+        limit = cache_sim.vmem_limit_bytes(p)
+    return {"state_bytes": p.state_bytes, "vmem_limit_bytes": limit}
 
 
 def _pad_to_segment(x: Array, n_to: int, fill: int) -> Array:
@@ -559,7 +572,7 @@ def _run_traces_segmented(p: cache_mod.CacheParams, addr: Array,
         sp.ready(carry)
         if sp:
             sp.add(program="static", backend=backend, row_steps=b * n_pad,
-                   segments=n_pad // segment)
+                   segments=n_pad // segment, **_state_counters(p, backend))
     l1p, l2p, stats, _ = carry
     return stats, cache_mod.unpack_state(l1p, l2p)
 

@@ -4,9 +4,9 @@ Off by default.  ``enable()`` starts a fresh recording, ``disable()``
 stops it and ``records()`` returns what was recorded.  The sweep engine
 opens six spans at its layer boundaries:
 
-================== ========================================== ==============
+================== ========================================== ====================
 span               where                                      counters
-================== ========================================== ==============
+================== ========================================== ====================
 ``sweep``          ``engine.sweep_results``, the whole body   ``rows``
 ``sweep.build``    ``engine.build_sweep_batch`` /             ``rows``,
                    ``build_tiering_batch``                    ``steps``,
@@ -19,16 +19,23 @@ trace``            those builders                             ``accesses``
 ``sweep.program``  dispatch to completion of the device       ``program``,
                    program in ``engine.run_traces`` and       ``backend``,
                    ``tiering_dyn.run_dynamic``                ``row_steps``,
-                                                              ``segments``
+                                                              ``segments``; static
+                                                              only:
+                                                              ``state_bytes``,
+                                                              ``vmem_limit_bytes``
 ``sweep.timing``   ``machine.time_batch``                     ``rows``
-================== ========================================== ==============
+================== ========================================== ====================
 
 ``rows`` and ``steps`` are the batch rows and padded scan steps, and
 ``row_steps`` their product as the program ran it (segment padding
 included); ``accesses`` are unpadded trace entries.  ``program`` is
 ``static`` or ``epoch`` and ``backend`` the implementation that ran it
-(``pallas`` or ``reference``, as ``engine.resolve_backend`` chose).  Every counter comes
-from shapes the host already knows, never from a device read.
+(``pallas`` or ``reference``, as ``engine.resolve_backend`` chose).
+``state_bytes`` is one row's cache state (``CacheParams.state_bytes``)
+and ``vmem_limit_bytes`` the scoped VMEM the static program's kernel
+compiled with (``cache_sim.vmem_limit_bytes``), 0 on the scan.  Every
+counter comes from shapes the host already knows, never from a device
+read.
 
 Each span records its name, its start and end on
 ``time.perf_counter_ns()`` (the clock a profiler session's

@@ -54,7 +54,7 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Tuple
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -245,9 +245,25 @@ class Layout:
         return (self.l2_sets // self.g2, self.g2 * self.l2_ways)
 
 
-#: Mosaic's default scoped-VMEM limit on a TPU v5e: what one kernel's
-#: blocks and scratch may hold, since no call here raises it.
-VMEM_LIMIT = 16 * 2 ** 20
+#: VMEM of one TensorCore by device kind, as ``pltpu.get_tpu_info``
+#: gives it, for a chip it cannot read: one that is only described, as
+#: in the compile tests.
+VMEM_BYTES = {"TPU v5 lite": 128 * 2 ** 20}
+#: VMEM a MESI kernel asks for beyond its blocks, for Mosaic's internal
+#: scratch.  The compiler for a described v5e accepts the blocks alone,
+#: at 10 and 80 MiB, so this is headroom, not a measured need.
+VMEM_MARGIN = 2 * 2 ** 20
+
+
+def chip_vmem_bytes(device_kind: Optional[str] = None) -> int:
+    """VMEM of one TensorCore: of ``device_kind`` from :data:`VMEM_BYTES`,
+    else of the chip JAX runs on (``pltpu.get_tpu_info``, which reads the
+    default device).  A kind it does not know raises."""
+    if device_kind is None:
+        return pltpu.get_tpu_info().vmem_capacity_bytes
+    if device_kind not in VMEM_BYTES:
+        raise ValueError(f"no VMEM capacity known for {device_kind!r}")
+    return VMEM_BYTES[device_kind]
 
 
 def _tiled_bytes(shape: Tuple[int, int]) -> int:
@@ -256,18 +272,32 @@ def _tiled_bytes(shape: Tuple[int, int]) -> int:
 
 
 def vmem_bytes(params: CacheParams) -> int:
-    """VMEM that :func:`mesi_segment`'s blocks take at this geometry.
+    """VMEM that a MESI kernel's blocks take at this geometry.
 
-    The stats row and the 8 state planes are output blocks and, again,
-    carry-in blocks, and the pipeline double-buffers each: 4 copies of
-    one row's state.  :func:`mesi_cache_sim` has no carry-in, so it takes
-    half.  2.7 MiB at the paper's Table-I host; an L2 of 16 MiB or more
-    (16-way) does not fit :data:`VMEM_LIMIT`.
+    The blocks are one row's state: the stats row and the 8 state
+    planes, each held in one buffer (its index does not change along a
+    row's blocks).  :func:`mesi_cache_sim` and :func:`mesi_segment` take
+    the same, since the segment kernel's carry-in stays in HBM.  0.68
+    MiB at the paper's Table-I host, 10.05 MiB at an 8-core CCD with a
+    32 MiB 16-way L3.
     """
     lay = Layout.of(params)
-    row = (_tiled_bytes((1, STAT_LANES)) + 3 * _tiled_bytes(lay.shape1)
-           + 5 * _tiled_bytes(lay.shape2))
-    return 4 * row
+    return (_tiled_bytes((1, STAT_LANES)) + 3 * _tiled_bytes(lay.shape1)
+            + 5 * _tiled_bytes(lay.shape2))
+
+
+def vmem_limit_bytes(params: CacheParams) -> int:
+    """The scoped VMEM both MESI kernels compile with: their blocks
+    (:func:`vmem_bytes`) and :data:`VMEM_MARGIN`."""
+    return vmem_bytes(params) + VMEM_MARGIN
+
+
+def fits_chip(params: CacheParams,
+              device_kind: Optional[str] = None) -> bool:
+    """Whether the MESI kernels compile at this geometry: their scoped
+    VMEM (:func:`vmem_limit_bytes`) fits the chip's
+    (:func:`chip_vmem_bytes`)."""
+    return vmem_limit_bytes(params) <= chip_vmem_bytes(device_kind)
 
 
 def _carry_planes(lay: Layout, l1p: Array, l2p: Array):
@@ -527,10 +557,18 @@ def _trace_blocks(chunk: int, interpret: bool, addr, *fields):
     return flat, spec, block, n_blocks
 
 
-def _state_specs(b: int, lay: Layout):
-    """Block specs and shapes of the stats row and the 8 state planes."""
+def _state_specs(b: int, lay: Layout, *, squeeze: bool = True):
+    """Block specs and shapes of the stats row and the 8 state planes.
+
+    A block's index changes with the row only, so the pipeline keeps it
+    in one buffer: a second would be used only while the previous row's
+    state is written back.  ``squeeze=False`` keeps the row axis in the
+    block, as (1, rows, lanes), the shape a DMA from HBM fills.
+    """
     def spec(shape):
-        return pl.BlockSpec((None,) + shape, lambda b_, j: (b_, 0, 0))
+        return pl.BlockSpec((None if squeeze else 1,) + shape,
+                            lambda b_, j: (b_, 0, 0),
+                            pipeline_mode=pl.Buffered(1))
     shapes = [(1, STAT_LANES)] + [lay.shape1] * 3 + [lay.shape2] * 5
     return ([spec(s) for s in shapes],
             [jax.ShapeDtypeStruct((b,) + s, jnp.int32) for s in shapes])
@@ -550,10 +588,14 @@ def mesi_cache_sim(addr: Array, is_write: Array, core: Array, tier: Array,
 
     VMEM: the state planes of one row, ``4 B * (3 * cores * l1_sets *
     l1_ways + 5 * l2_sets * l2_ways)`` (688 KiB at the paper's Table-I
-    host: 4 cores, 64 KiB L1, 2 MiB L2; the planes are lane-dense, so
-    nothing pads), held in the output blocks, which the pipeline
-    double-buffers: 1.34 MiB.  The trace blocks live
-    in SMEM (4 fields x 1,024 entries x 4 B, double-buffered: 32 KiB).
+    host: 4 cores, 64 KiB L1, 2 MiB L2; 10.05 MiB at an 8-core CCD with
+    a 32 MiB L3; the planes are lane-dense, so nothing pads), held once
+    in the output blocks.  The kernel compiles with that much scoped
+    VMEM and :data:`VMEM_MARGIN` (:func:`vmem_limit_bytes`), and not
+    with Mosaic's default of 16 MiB, so any geometry compiles whose
+    state fits the chip's VMEM (:func:`fits_chip`; 128 MiB on a v5e).
+    The trace blocks live in SMEM (4 fields x 1,024 entries x 4 B,
+    double-buffered: 32 KiB).
 
     Args:
       addr: (B, N) int32 line addresses; `SENTINEL` (-1) marks padding
@@ -583,6 +625,8 @@ def mesi_cache_sim(addr: Array, is_write: Array, core: Array, tier: Array,
         in_specs=[trace_spec] * 4,
         out_specs=out_specs,
         out_shape=out_shape,
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes(params)),
         interpret=interpret,
     )(*trace)
     return stats[:, 0, :nstats(params.n_targets)], _state_of(lay, planes)
@@ -592,27 +636,28 @@ def mesi_cache_sim(addr: Array, is_write: Array, core: Array, tier: Array,
 # Carry-in / carry-out segment kernel (streaming + checkpoint/resume)
 # ---------------------------------------------------------------------------
 def _mesi_segment_kernel(addr_ref, w_ref, core_ref, tier_ref, t0_ref,
-                         stats_in, *refs, block: int, lay: Layout,
-                         n_targets: int):
+                         *refs, block: int, lay: Layout, n_targets: int):
     """Segment variant of :func:`_mesi_kernel`: state flows carry->carry.
 
     Instead of a fresh state at each row's first block, the incoming
-    carry (state planes, stats, logical clock t0) seeds the output
+    carry (stats, state planes, logical clock t0) seeds the output
     blocks, so a trace split into segments threads identical arithmetic
     through the carry — the resumable-stream contract of
-    :func:`repro.core.engine.run_batch_segment`.
+    :func:`repro.core.engine.run_batch_segment`.  The carry-in stays in
+    HBM, where it shares its buffer with the carry-out, and is copied
+    into the output blocks once a row: VMEM holds one copy of the state.
     """
-    planes_in, (stats, *planes) = refs[:8], refs[8:]
-    j = pl.program_id(1)
+    carry_in, blocks = refs[:9], refs[9:]
+    b, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when(j == 0)
     def _init():
-        for dst, src in zip(planes, planes_in):
-            dst[...] = src[...]
-        stats[...] = stats_in[...]
+        for dst, src in zip(blocks, carry_in):
+            pltpu.sync_copy(src.at[pl.ds(b, 1)], dst)
 
+    stats, *planes = (ref.at[0] for ref in blocks)
     _run_chunk((addr_ref, w_ref, core_ref, tier_ref), planes, stats,
-               t0_ref[pl.program_id(0)] + j * block, block=block, lay=lay,
+               t0_ref[b] + j * block, block=block, lay=lay,
                n_targets=n_targets)
 
 
@@ -628,7 +673,8 @@ def mesi_segment(carry, addr: Array, is_write: Array, core: Array,
     ``run_batch_segment`` threads between segments and what checkpoint/
     resume snapshots — so segments may alternate freely between this
     kernel and the reference scan with bitwise-identical results.  VMEM
-    as :func:`mesi_cache_sim`, plus the double-buffered carry-in planes.
+    as :func:`mesi_cache_sim`: the carry-in stays in HBM, aliased to the
+    carry-out, and is copied into the output blocks at each row's start.
 
     Args:
       carry: ``(l1p, l2p, stats, t)`` packed batch carry (leading B).
@@ -652,7 +698,7 @@ def mesi_segment(carry, addr: Array, is_write: Array, core: Array,
     lay = Layout.of(params)
     trace, trace_spec, block, n_blocks = _trace_blocks(
         chunk, interpret, addr, is_write, core, tier)
-    state_specs, out_shape = _state_specs(b, lay)
+    state_specs, out_shape = _state_specs(b, lay, squeeze=False)
     kernel = functools.partial(_mesi_segment_kernel, block=block, lay=lay,
                                n_targets=params.n_targets)
     stats_in = jnp.zeros((b, 1, STAT_LANES), jnp.int32).at[:, 0, :ns].set(
@@ -661,9 +707,13 @@ def mesi_segment(carry, addr: Array, is_write: Array, core: Array,
         kernel,
         grid=(b, n_blocks),
         in_specs=[trace_spec] * 4 + [pl.BlockSpec(memory_space=pltpu.SMEM)]
-        + state_specs,
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(state_specs),
         out_specs=state_specs,
         out_shape=out_shape,
+        # the carry-in (stats row, then planes) becomes the carry-out
+        input_output_aliases={5 + k: k for k in range(len(state_specs))},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes(params)),
         interpret=interpret,
     )(*trace, t.astype(jnp.int32).reshape(b), stats_in,
       *_carry_planes(lay, l1p, l2p))

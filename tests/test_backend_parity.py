@@ -8,6 +8,10 @@ interpret mode (the CPU parity oracle for the TPU kernels).  The two
 backends expose the *same* carry, so segments may alternate backends
 freely and a checkpoint written by one resumes on the other.
 """
+import importlib.util
+import json
+import pathlib
+
 import numpy as np
 import pytest
 from jax.tree_util import tree_map as jax_tree_map
@@ -78,16 +82,21 @@ def test_backend_resolves_by_platform_and_program(monkeypatch, platform,
     assert engine.resolve_backend(backend, epoch=epoch) == want
 
 
-@pytest.mark.parametrize("l2_mib,want", [
-    (2, "pallas"),        # Table I: 2.7 MiB of kernel state blocks
-    (8, "pallas"),        # 10.2 MiB, the largest 16-way L2 that fits
-    (16, "reference"),    # 20.2 MiB, over Mosaic's 16 MiB scoped VMEM
+@pytest.mark.parametrize("cores,l1_kib,l2_mib,want", [
+    (4, 64, 2, "pallas"),        # Table I: 0.68 MiB of kernel blocks
+    (8, 32, 32, "pallas"),       # a Genoa CCD's 32 MiB L3: 10.05 MiB
+    (4, 64, 256, "pallas"),      # 80.05 MiB, the largest 16-way L2 that fits
+    (4, 64, 512, "reference"),   # 160.05 MiB, over a v5e's 128 MiB of VMEM
 ])
 def test_default_backend_keeps_the_scan_where_the_state_outgrows_vmem(
-        monkeypatch, l2_mib, want):
-    from repro.kernels import ops
+        monkeypatch, cores, l1_kib, l2_mib, want):
+    from repro.kernels import cache_sim, ops
     monkeypatch.setattr(ops, "platform", lambda: "tpu")
-    p = C.CacheParams(cores=4, l2_bytes=l2_mib * 2 ** 20)
+    monkeypatch.setattr(cache_sim, "chip_vmem_bytes",
+                        lambda device_kind=None:
+                        cache_sim.VMEM_BYTES["TPU v5 lite"])
+    p = C.CacheParams(cores=cores, l1_bytes=l1_kib * 1024,
+                      l2_bytes=l2_mib * 2 ** 20)
     assert engine.resolve_backend(None, p) == want
     assert engine.resolve_backend("pallas", p) == "pallas"
 
@@ -117,6 +126,47 @@ def test_static_parity():
     ref = engine.run_traces(CACHE, *args)
     pal = engine.run_traces(CACHE, *args, backend="pallas", chunk=64)
     assert_run_equal(pal, ref)
+
+
+# ---------------------------------------------------------------------------
+# a server host: 8 cores and a many-set 16-way shared level
+# ---------------------------------------------------------------------------
+BENCH = pathlib.Path(__file__).resolve().parents[1] / "benchmarks" / "chip"
+
+
+def _bench_module(name):
+    spec = importlib.util.spec_from_file_location(f"bench_{name}",
+                                                  BENCH / f"{name}.py")
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def test_server_llc_parity_with_the_scan_and_the_plain_reference():
+    """The benchmark's Genoa CCD (8 cores, 8-way L1s, 16-way shared L3)
+    under its hot/cold traffic and 1:1 interleave, with the caches cut
+    to 4 KiB and 64 KiB (64 sets): the kernel's rows equal the scan's,
+    bitwise, and pass the plain reference's comparison."""
+    grid, reference, compare = (_bench_module(n) for n in
+                                ("grid", "reference", "compare"))
+    cfg = json.loads((BENCH / "configs" / "genoa-ccd-direct1.json")
+                     .read_text())
+    cfg["cache"].update(l1_bytes=4096, l2_bytes=64 * 1024)
+    traffic = json.loads((BENCH / "traffic" / "hotcold-llc.json")
+                         .read_text())
+    sim = grid.simulator(cfg)
+    sweep = grid.sweep_grid(cfg, traffic, 2 ** 31 + 17)
+    rows = {b: sim.sweep(**sweep, backend=b)
+            for b in ("reference", "pallas")}
+    assert repr(rows["pallas"]) == repr(rows["reference"])
+    # the sharded, streamed path pmaps the segment kernel
+    sharded = sim.sweep(**sweep, backend="pallas", mesh=2,
+                        stream_chunk=1024)
+    assert repr(sharded) == repr(rows["reference"])
+    want = reference.sweep_rows(cfg, traffic, 2 ** 31 + 17)
+    values = compare.compare([rows["pallas"]], want)
+    assert compare.passed(values), values
+    assert 0 < rows["pallas"][0]["l2_miss_rate"] < 1
 
 
 # ---------------------------------------------------------------------------

@@ -178,6 +178,24 @@ def test_program_span_names_the_backend_that_ran(backend, segment):
     assert program.counters["backend"] == backend
 
 
+@pytest.mark.parametrize("segment", [None, 256])
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_program_span_counts_the_state_and_the_kernels_vmem(backend,
+                                                            segment):
+    from repro.kernels import cache_sim
+    rng = np.random.default_rng(7)
+    addr = jnp.asarray(rng.integers(0, 512, (2, 300)), jnp.int32)
+    _, recs = _recorded(lambda: engine.run_traces(
+        CACHE, addr, None, backend=backend, chunk=128, segment=segment))
+    (program,) = _named(recs, "sweep.program")
+    # 8 KiB 2-way L1 and 16 KiB 8-way L2 of 64 B lines: 128 and 256
+    # lines, 3 and 5 int32 fields a line
+    assert program.counters["state_bytes"] == 4 * (3 * 128 + 5 * 256)
+    assert program.counters["vmem_limit_bytes"] == (
+        cache_sim.vmem_bytes(CACHE) + cache_sim.VMEM_MARGIN
+        if backend == "pallas" else 0)
+
+
 def test_off_records_nothing_opens_nothing_and_listens_to_nothing(
         monkeypatch):
     from jax._src import monitoring

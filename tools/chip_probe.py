@@ -8,9 +8,11 @@
 ``static`` times one warm call of the static segment program
 (``engine.run_batch_segment``) over 2,048 random accesses per row, from
 random cores, at the paper's Table-I geometry (4 cores, 64 KiB 8-way
-L1, 2 MiB 16-way L2, five route targets) for each batch width, and once
-at a small geometry (8 KiB 2-way L1, 16 KiB 8-way L2) at B=8, on each
-backend (the reference scan and the Pallas kernel); it prints
+L1, 2 MiB 16-way L2, five route targets) for each batch width, once
+at a small geometry (8 KiB 2-way L1, 16 KiB 8-way L2) at B=8, and at
+one AMD EPYC 9004 CCD (8 cores, 32 KiB 8-way L1, a 32 MiB 16-way L3 as
+the shared level, two targets) at B=1 and 2, on each backend (the
+reference scan and the Pallas kernel); it prints
 microseconds per scan step and whether the two backends' carries are
 bitwise equal.  ``dynamic`` does the same for one 4,096-access epoch
 slot of the epoch program (``tiering_dyn.run_dynamic_segment``, 1,026
@@ -43,9 +45,9 @@ def _timed(fn):
     return time.perf_counter() - t
 
 
-def _trace(rng, b, n, n_targets, cores=1):
+def _trace(rng, b, n, n_targets, cores=1, footprint=4 * 2 ** 20):
     import jax.numpy as jnp
-    lines = 4 * 2 ** 20 // 64          # a 4 MiB footprint, 2 x L2
+    lines = footprint // 64            # 4 MiB: 2 x Table I's L2
     return (jnp.asarray(rng.integers(0, lines, (b, n)), jnp.int32),
             jnp.asarray(rng.integers(0, 2, (b, n)), jnp.int32),
             jnp.asarray(rng.integers(0, cores, (b, n)), jnp.int32),
@@ -62,9 +64,13 @@ def static(tag, rng, batches) -> None:
     small = cache_mod.CacheParams(l1_bytes=8 * 1024, l1_ways=2,
                                   l2_bytes=16 * 1024, l2_ways=8,
                                   n_targets=5)
-    for name, p, bs in (("Table I", table1, batches), ("small", small, (8,))):
+    genoa = cache_mod.CacheParams(cores=8, l1_bytes=32 * 1024,
+                                  l2_bytes=32 * 2 ** 20, n_targets=2)
+    for name, p, bs in (("Table I", table1, batches), ("small", small, (8,)),
+                        ("Genoa CCD", genoa, (1, 2))):
+        fp = 2 * max(p.l2_bytes, table1.l2_bytes)
         for b in bs:
-            trace = _trace(rng, b, STEPS, p.n_targets, p.cores)
+            trace = _trace(rng, b, STEPS, p.n_targets, p.cores, fp)
             carry = engine.init_batch_carry(p, b)
             outs = []
             for backend in engine.BACKENDS:
