@@ -44,9 +44,9 @@ Backends
     mode elsewhere (parity validation — keep geometries small).
 
 ``backend=None``, the default everywhere, resolves by platform
-(:func:`resolve_backend`): the static program runs the compiled kernel
-on a TPU and the reference scan elsewhere; the epoch program runs the
-reference scan, since Mosaic does not lower its kernel yet.
+(:func:`resolve_backend`): the static and the epoch program run their
+compiled kernels on a TPU, where one row's state fits the chip's VMEM,
+and the reference scan elsewhere.
 """
 from __future__ import annotations
 
@@ -79,27 +79,26 @@ BACKENDS = ("reference", "pallas")
 
 def resolve_backend(backend: Optional[str],
                     params: Optional[cache_mod.CacheParams] = None, *,
-                    epoch: bool = False) -> str:
+                    epoch: bool = False, n_pages: int = 0) -> str:
     """The implementation a program runs: ``backend`` when given, else
     the platform's.
 
-    ``None`` resolves to the compiled Pallas kernel for the static
-    program where jitted calls run on a TPU (the default device's
-    platform) and the kernels' blocks for one batch row fit the chip's
-    VMEM (:func:`repro.kernels.cache_sim.fits_chip` of ``params``, when
-    given), and to the reference scan on any other platform, for a
-    larger cache, and for the epoch program (``epoch=True``), whose
-    kernel Mosaic does not lower yet.  An explicit name is only checked:
+    ``None`` resolves to the compiled Pallas kernel where jitted calls
+    run on a TPU (the default device's platform) and the kernel's blocks
+    for one batch row fit the chip's VMEM
+    (:func:`repro.kernels.cache_sim.fits_chip` of ``params``, when
+    given; for the epoch program, ``epoch=True``, with its ``n_pages``
+    page planes), and to the reference scan on any other platform and
+    for a larger cache.  An explicit name is only checked:
     ``'reference'`` always runs the scan, and ``'pallas'`` where it
     cannot compile raises.
     """
     if backend is None:
-        if epoch:
-            return "reference"
         from repro.kernels import cache_sim, ops
         if ops.platform() != "tpu":
             return "reference"
-        fits = params is None or cache_sim.fits_chip(params)
+        fits = params is None or cache_sim.fits_chip(
+            params, n_pages=n_pages if epoch else None)
         return "pallas" if fits else "reference"
     if backend not in BACKENDS:
         raise ValueError(f"unknown backend {backend!r}; pick from {BACKENDS}")
@@ -883,18 +882,18 @@ def sweep_results(spec: SweepSpec, cache: cache_mod.CacheParams,
     with obs.span("sweep") as sweep_span:
         epoch = (any(tr is not None for tr in spec.tiering_axis)
                  or any(sp is not None for sp in spec.sampling_axis))
-        backend = resolve_backend(spec.backend, cache, epoch=epoch)
         executor = _resolve_executor(executor, resume, fault_plan, report)
         executor = executor if executor is not None else _LOCAL_EXECUTOR
         routes = [None if tp is None else route_mod.build_route(tp, timing)
                   for tp in spec.topology_axis]
         if epoch:
             out = _sweep_results_dynamic(spec, cache, timing, routes,
-                                         backend=backend, executor=executor)
+                                         executor=executor)
         else:
-            out = _sweep_results_static(spec, cache, timing, routes,
-                                        backend=backend, chunk=chunk,
-                                        executor=executor)
+            out = _sweep_results_static(
+                spec, cache, timing, routes,
+                backend=resolve_backend(spec.backend, cache), chunk=chunk,
+                executor=executor)
         if sweep_span:
             sweep_span.add(rows=len(out))
     return out
@@ -1113,7 +1112,7 @@ def _build_tiering_batch(spec, cache, routes, slot, t_max):
 def _sweep_results_dynamic(spec: SweepSpec, cache: cache_mod.CacheParams,
                            timing: TimingConfig,
                            routes: Sequence[Optional[route_mod.RouteMap]],
-                           *, backend: str, executor) -> List[RunResult]:
+                           *, executor) -> List[RunResult]:
     """The epoch-structured twin of the static `sweep_results` body.
 
     One `tiering_dyn.run_dynamic` device call simulates every
@@ -1149,6 +1148,8 @@ def _sweep_results_dynamic(spec: SweepSpec, cache: cache_mod.CacheParams,
                 f"epoch_len {tr.epoch_len} is not a multiple of the "
                 f"sweep's epoch gcd {slot}")
     tb = build_tiering_batch(spec, cache, routes, slot, t_max)
+    backend = resolve_backend(spec.backend, p, epoch=True,
+                              n_pages=tb.page_map0.shape[1])
     out = executor.run_dynamic(p, tb, slot_len=slot, k_max=k_max,
                                backend=backend)
     stats = np.asarray(jax.block_until_ready(out.stats), np.int64)

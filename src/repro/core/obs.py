@@ -19,10 +19,10 @@ trace``            those builders                             ``accesses``
 ``sweep.program``  dispatch to completion of the device       ``program``,
                    program in ``engine.run_traces`` and       ``backend``,
                    ``tiering_dyn.run_dynamic``                ``row_steps``,
-                                                              ``segments``; static
-                                                              only:
-                                                              ``state_bytes``,
+                                                              ``segments``,
                                                               ``vmem_limit_bytes``
+                                                              (static also
+                                                              ``state_bytes``)
 ``sweep.timing``   ``machine.time_batch``                     ``rows``
 ================== ========================================== ====================
 
@@ -32,8 +32,9 @@ included); ``accesses`` are unpadded trace entries.  ``program`` is
 ``static`` or ``epoch`` and ``backend`` the implementation that ran it
 (``pallas`` or ``reference``, as ``engine.resolve_backend`` chose).
 ``state_bytes`` is one row's cache state (``CacheParams.state_bytes``)
-and ``vmem_limit_bytes`` the scoped VMEM the static program's kernel
-compiled with (``cache_sim.vmem_limit_bytes``), 0 on the scan.  Every
+and ``vmem_limit_bytes`` the scoped VMEM the program's kernel compiled
+with (``cache_sim.vmem_limit_bytes``; the epoch kernel's with its page
+count), 0 on the scan.  Every
 counter comes from shapes the host already knows, never from a device
 read.
 

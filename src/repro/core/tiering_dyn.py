@@ -663,10 +663,16 @@ def run_dynamic(p: cache_mod.CacheParams, addr, is_write, core, tier,
                                          segment_slots, backend)
         sp.ready(out)
         if sp:
+            limit = 0
+            if backend == "pallas":
+                from repro.kernels import cache_sim
+                limit = cache_sim.vmem_limit_bytes(
+                    p, n_pages=page_map0.shape[1])
             sp.add(program="epoch", backend=backend,
                    row_steps=b * e * slot_len,
                    segments=(1 if segment_slots is None
-                             else -(-e // segment_slots)))
+                             else -(-e // segment_slots)),
+                   vmem_limit_bytes=limit)
     return out
 
 

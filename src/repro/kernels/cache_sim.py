@@ -31,9 +31,9 @@ The TPU-native design shared by both:
     each row's first chunk, so a whole multi-config sweep is one kernel
     launch.
 
-`mesi_cache_sim` and `mesi_segment` compile for a TPU v5e
-(`tests/test_chip_compile.py`) and are what the static program runs
-there by default; Mosaic still refuses `mesi_dyn_segment`'s trace blocks.
+`mesi_cache_sim`, `mesi_segment` and the epoch program's
+`mesi_dyn_segment` compile for a TPU v5e (`tests/test_chip_compile.py`)
+and are what the static and the epoch program run there by default.
 
 Sentinel padding convention
 ---------------------------
@@ -188,6 +188,8 @@ STAT_LANES = 128
 TRACE_TILE = 1024
 _NO_LANE = 1 << 30                   # above every lane index: "no match"
 _INT_MAX = 2 ** 31 - 1
+#: Per-slot output rows of the epoch kernel in one block: one sublane tile.
+_SLOT_ROWS = 8
 
 
 def _sets_per_row(sets: int, width: int) -> int:
@@ -271,7 +273,16 @@ def _tiled_bytes(shape: Tuple[int, int]) -> int:
     return 4 * (-(-shape[0] // 8) * 8) * (-(-shape[1] // 128) * 128)
 
 
-def vmem_bytes(params: CacheParams) -> int:
+def _page_rows(n_pages: int) -> int:
+    """Rows of a lane-dense per-page plane: page ``i`` sits in row
+    ``i // 128``, lane ``i % 128``.  The rows fill whole (8, 128) tiles:
+    on a v5e the carry-in copy of a plane that ends in part of a tile
+    never completed."""
+    return -(-n_pages // 1024) * 8
+
+
+def vmem_bytes(params: CacheParams, *, n_pages: Optional[int] = None
+               ) -> int:
     """VMEM that a MESI kernel's blocks take at this geometry.
 
     The blocks are one row's state: the stats row and the 8 state
@@ -280,24 +291,37 @@ def vmem_bytes(params: CacheParams) -> int:
     the same, since the segment kernel's carry-in stays in HBM.  0.68
     MiB at the paper's Table-I host, 10.05 MiB at an 8-core CCD with a
     32 MiB 16-way L3.
+
+    ``n_pages`` counts instead the epoch kernel (:func:`mesi_dyn_segment`)
+    over that many pages: besides, its page map, page counters and
+    per-target page lines as lane-dense page planes, its two migration
+    rows, and its double-buffered block of per-slot rows.
     """
     lay = Layout.of(params)
-    return (_tiled_bytes((1, STAT_LANES)) + 3 * _tiled_bytes(lay.shape1)
-            + 5 * _tiled_bytes(lay.shape2))
+    total = (_tiled_bytes((1, STAT_LANES)) + 3 * _tiled_bytes(lay.shape1)
+             + 5 * _tiled_bytes(lay.shape2))
+    if n_pages is not None:
+        page_plane = _tiled_bytes((_page_rows(n_pages), 128))
+        total += ((2 + params.n_targets) * page_plane
+                  + 2 * _tiled_bytes((1, STAT_LANES))
+                  + 2 * _tiled_bytes((_SLOT_ROWS, STAT_LANES)))
+    return total
 
 
-def vmem_limit_bytes(params: CacheParams) -> int:
-    """The scoped VMEM both MESI kernels compile with: their blocks
+def vmem_limit_bytes(params: CacheParams, *,
+                     n_pages: Optional[int] = None) -> int:
+    """The scoped VMEM a MESI kernel compiles with: its blocks
     (:func:`vmem_bytes`) and :data:`VMEM_MARGIN`."""
-    return vmem_bytes(params) + VMEM_MARGIN
+    return vmem_bytes(params, n_pages=n_pages) + VMEM_MARGIN
 
 
-def fits_chip(params: CacheParams,
-              device_kind: Optional[str] = None) -> bool:
-    """Whether the MESI kernels compile at this geometry: their scoped
-    VMEM (:func:`vmem_limit_bytes`) fits the chip's
-    (:func:`chip_vmem_bytes`)."""
-    return vmem_limit_bytes(params) <= chip_vmem_bytes(device_kind)
+def fits_chip(params: CacheParams, device_kind: Optional[str] = None, *,
+              n_pages: Optional[int] = None) -> bool:
+    """Whether a MESI kernel compiles at this geometry: its scoped VMEM
+    (:func:`vmem_limit_bytes`; the epoch kernel's with ``n_pages``)
+    fits the chip's (:func:`chip_vmem_bytes`)."""
+    return (vmem_limit_bytes(params, n_pages=n_pages)
+            <= chip_vmem_bytes(device_kind))
 
 
 def _carry_planes(lay: Layout, l1p: Array, l2p: Array):
@@ -557,8 +581,19 @@ def _trace_blocks(chunk: int, interpret: bool, addr, *fields):
     return flat, spec, block, n_blocks
 
 
-def _state_specs(b: int, lay: Layout, *, squeeze: bool = True):
-    """Block specs and shapes of the stats row and the 8 state planes.
+def _lane_row(x: Array) -> Array:
+    """(B, w) -> (B, 1, STAT_LANES): value ``k`` of a row in lane ``k``."""
+    return jnp.zeros((x.shape[0], 1, STAT_LANES), jnp.int32).at[
+        :, 0, :x.shape[1]].set(jnp.asarray(x, jnp.int32))
+
+
+def _state_shapes(lay: Layout):
+    """Block shapes of the stats row and the 8 state planes."""
+    return [(1, STAT_LANES)] + [lay.shape1] * 3 + [lay.shape2] * 5
+
+
+def _state_specs(b: int, shapes, *, squeeze: bool = True):
+    """Block specs and (B, ...) array shapes of a row's state blocks.
 
     A block's index changes with the row only, so the pipeline keeps it
     in one buffer: a second would be used only while the previous row's
@@ -569,7 +604,6 @@ def _state_specs(b: int, lay: Layout, *, squeeze: bool = True):
         return pl.BlockSpec((None if squeeze else 1,) + shape,
                             lambda b_, j: (b_, 0, 0),
                             pipeline_mode=pl.Buffered(1))
-    shapes = [(1, STAT_LANES)] + [lay.shape1] * 3 + [lay.shape2] * 5
     return ([spec(s) for s in shapes],
             [jax.ShapeDtypeStruct((b,) + s, jnp.int32) for s in shapes])
 
@@ -616,7 +650,7 @@ def mesi_cache_sim(addr: Array, is_write: Array, core: Array, tier: Array,
     lay = Layout.of(params)
     trace, trace_spec, block, n_blocks = _trace_blocks(
         chunk, interpret, addr, is_write, core, tier)
-    out_specs, out_shape = _state_specs(b, lay)
+    out_specs, out_shape = _state_specs(b, _state_shapes(lay))
     kernel = functools.partial(_mesi_kernel, block=block, lay=lay,
                                n_targets=params.n_targets)
     stats, *planes = pl.pallas_call(
@@ -698,11 +732,10 @@ def mesi_segment(carry, addr: Array, is_write: Array, core: Array,
     lay = Layout.of(params)
     trace, trace_spec, block, n_blocks = _trace_blocks(
         chunk, interpret, addr, is_write, core, tier)
-    state_specs, out_shape = _state_specs(b, lay, squeeze=False)
+    state_specs, out_shape = _state_specs(b, _state_shapes(lay),
+                                          squeeze=False)
     kernel = functools.partial(_mesi_segment_kernel, block=block, lay=lay,
                                n_targets=params.n_targets)
-    stats_in = jnp.zeros((b, 1, STAT_LANES), jnp.int32).at[:, 0, :ns].set(
-        jnp.asarray(stats, jnp.int32))
     outs = pl.pallas_call(
         kernel,
         grid=(b, n_blocks),
@@ -715,7 +748,7 @@ def mesi_segment(carry, addr: Array, is_write: Array, core: Array,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=vmem_limit_bytes(params)),
         interpret=interpret,
-    )(*trace, t.astype(jnp.int32).reshape(b), stats_in,
+    )(*trace, t.astype(jnp.int32).reshape(b), _lane_row(stats),
       *_carry_planes(lay, l1p, l2p))
     l1p_o, l2p_o = _pack_planes(lay, outs[1:])
     return (l1p_o, l2p_o, outs[0][:, 0, :ns], t + jnp.int32(n))
@@ -731,87 +764,94 @@ def mesi_segment(carry, addr: Array, is_write: Array, core: Array,
 DYN_SCALARS = ("dyn_flag", "n_pages", "budget", "threshold", "period",
                "dram_cap", "ssd_tid", "cxl_cap", "s_warm", "s_meas",
                "s_per", "t0", "eidx0")
+#: First lane of a per-slot output row past its stat snapshot: the four
+#: :data:`repro.core.tiering_dyn.SLOT_FIELDS` counters, then the slot's
+#: measurement flag.
+SLOT_LANE = STAT_LANES - 5
+#: A line's page is its address shifted right by this many bits (a
+#: floor division by :data:`LINES_PER_PAGE`, a power of two).
+_PAGE_SHIFT = LINES_PER_PAGE.bit_length() - 1
+if LINES_PER_PAGE != 1 << _PAGE_SHIFT:
+    raise ValueError(f"{LINES_PER_PAGE} lines a page is not a power of two")
+
+
+def _top_keys(keys: Array, n, k_max: int) -> Array:
+    """Mask of the ``n`` largest of a plane's keys, ``n <= k_max``.
+
+    Valid keys are distinct and non-negative (:func:`repro.core.
+    tiering_dyn.encode_hot_key`) and every other entry is -1, so the
+    ``r``-th largest key is the maximum below the ``(r-1)``-th, and the
+    pages whose key reaches the ``n``-th are exactly the ``n`` that
+    ``lax.top_k`` picks.  The caller keeps ``n`` within the count of
+    valid keys; ``n == 0`` selects nothing.
+    """
+    def body(r, carry):
+        prev, kth = carry
+        m = jnp.max(jnp.where(keys < prev, keys, -1), axis=(0, 1),
+                    keepdims=True)
+        return m, jnp.where(r < n, m, kth)
+
+    top = jnp.full((1, 1), _INT_MAX, jnp.int32)
+    _, kth = jax.lax.fori_loop(0, k_max, body, (top, top))
+    return keys >= kth
 
 
 def _mesi_dyn_kernel(addr_ref, w_ref, core_ref, tier_ref, sc_ref, ptl_ref,
-                     l1t_in, l1u_in, l1s_in, l2t_in, l2u_in, l2s_in,
-                     l2tier_in, l2sh_in, stats_in, pmap_in, counts_in,
-                     migr_in, migw_in,
-                     stats_ref, l1t_ref, l1u_ref, l1s_ref,
-                     l2t_ref, l2u_ref, l2s_ref, l2tier_ref, l2sh_ref,
-                     pmap_ref, counts_ref, migr_ref, migw_ref,
-                     slots_ref, snaps_ref, meas_ref,
-                     l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh, stats,
-                     pmap_s, counts_s, migr_s, migw_s,
-                     *, slot_len: int, lay: Layout, n_slots: int,
-                     n_targets: int, n_p: int, k_max: int,
+                     *refs, block: int, n_blocks: int, slot_len: int,
+                     lay: Layout, n_targets: int, n_p: int, k_max: int,
                      count_bound: int):
-    """One (batch-row, epoch-slot) grid step of the dynamic tierer.
+    """One (batch-row, trace-block) grid step of the dynamic tierer.
 
-    Mirrors :func:`repro.core.tiering_dyn._slot_step` decision-for-
-    decision: the page map routes each access (DRAM vs the precomputed
-    CXL decode target), per-page counters accumulate in VMEM scratch,
-    and at each epoch boundary the promotion/demotion rule rewrites the
-    map via the same injective hotness keys — selected by an iterative
-    argmax (``k_max`` rounds) that picks exactly the pages
-    ``lax.top_k`` would, so migration totals and the map evolution are
-    bitwise-equal to the reference scan.  Sampled rows gate every stat
-    increment on the slot's measurement flag (the stat-masking
-    multiply), which equals the reference's per-slot delta masking
-    because stat updates are integer adds.
+    Mirrors :func:`repro.core.tiering_dyn._slot_step` decision for
+    decision.  A slot is ``n_blocks`` SMEM trace blocks.  Each access
+    reads its page's intent from the page map, routes to DRAM or the
+    precomputed CXL target (level-2 pages to the SSD target), runs
+    :func:`_mesi_access` and counts the page; sampled rows gate every
+    stat increment on the slot's measurement flag, which equals the
+    reference's masking of the slot's stat delta since stats are
+    integer adds.  After a slot's last block the kernel writes the
+    slot's output row and, at an epoch boundary, runs the migration
+    stages on the page planes, where :func:`_top_keys` selects the
+    pages that ``lax.top_k`` would.
+
+    The carry (stats row, 8 state planes, page map and counter planes,
+    migration rows) lives in output blocks that stay in VMEM along a
+    row; the carry-in stays in HBM, shares its buffer with the
+    carry-out and is copied in at each row's first block.
     """
-    j = pl.program_id(1)
-    ns = nstats(n_targets)
+    carry_in, blocks = refs[:13], refs[13:26]
+    slot_out, acc = refs[26:]
+    b, g = pl.program_id(0), pl.program_id(1)
 
-    # seed the full tierer carry from the inputs at each row's first slot
-    @pl.when(j == 0)
+    @pl.when(g == 0)
     def _init():
-        l1t[...] = l1t_in[0]
-        l1u[...] = l1u_in[0]
-        l1s[...] = l1s_in[0]
-        l2t[...] = l2t_in[0]
-        l2u[...] = l2u_in[0]
-        l2s[...] = l2s_in[0]
-        l2tier[...] = l2tier_in[0]
-        l2sh[...] = l2sh_in[0]
-        stats[...] = jnp.zeros(stats.shape, jnp.int32)
-        stats[:, :ns] = stats_in[...]
-        pmap_s[...] = pmap_in[0]
-        counts_s[...] = counts_in[0]
-        migr_s[...] = migr_in[0]
-        migw_s[...] = migw_in[0]
+        for dst, src in zip(blocks, carry_in):
+            pltpu.sync_copy(src.at[pl.ds(b, 1)], dst)
 
-    flag = sc_ref[0, 0]
-    npg = sc_ref[0, 1]
-    bud = sc_ref[0, 2]
-    thr = sc_ref[0, 3]
-    per = sc_ref[0, 4]
-    cap = sc_ref[0, 5]
-    ssd_t = sc_ref[0, 6]
-    l1cap = sc_ref[0, 7]
-    s_w = sc_ref[0, 8]
-    s_m = sc_ref[0, 9]
-    s_p = sc_ref[0, 10]
-    t0 = sc_ref[0, 11]
-    eidx0 = sc_ref[0, 12]
+    stats, *rest = (ref.at[0] for ref in blocks)
+    planes, (pmap, counts, migr, migw) = rest[:8], rest[8:]
+    (flag, npg, bud, thr, per, cap, ssd_t, l1cap, s_w, s_m, s_p, t0,
+     eidx0) = (sc_ref[b * len(DYN_SCALARS) + k]
+               for k in range(len(DYN_SCALARS)))
     lpp = jnp.int32(LINES_PER_PAGE)
-    base_t = t0 + j * slot_len
-    eidx = eidx0 + j                      # slot index entering this slot
+    i32 = lambda x: x.astype(jnp.int32)
+    slot, k = g // n_blocks, g % n_blocks
+    eidx = eidx0 + slot                   # slot index entering this slot
     # sampled rows (s_p > 0): slots outside [s_w, s_w + s_m) of each
     # period functionally warm (state advances, counters freeze)
-    pos = eidx % jnp.maximum(s_p, jnp.int32(1))
-    meas = jnp.where(s_p > 0, (pos >= s_w) & (pos < s_w + s_m),
-                     True).astype(jnp.int32)
+    pos = eidx % jnp.maximum(s_p, 1)
+    meas = i32(jnp.where(s_p > 0, (pos >= s_w) & (pos < s_w + s_m), True))
+    base_t = t0 + slot * slot_len + k * block
+    lane = jax.lax.broadcasted_iota(jnp.int32, (1, STAT_LANES), 1)
 
-    planes = (l1t, l1u, l1s, l2t, l2u, l2s, l2tier, l2sh)
-
-    def body(i, acc):
-        acc_t, acc_d, acc_s = acc
-        a_raw = addr_ref[0, 0, i]
-        v = (a_raw >= 0).astype(jnp.int32)
-        page = jnp.clip(a_raw // lpp, 0, n_p - 1)
-        intent = pmap_s[page]
-        tr_s = tier_ref[0, 0, i]
+    def body(i, carry):
+        acc_t, acc_d, st = carry
+        a_raw = addr_ref[i]
+        v = i32(a_raw >= 0)
+        page = jnp.clip(a_raw >> _PAGE_SHIFT, 0, n_p - 1)
+        q, at = pl.ds(page >> 7, 1), lane == (page & 127)
+        intent = jnp.sum(jnp.where(at, pmap[q, :], 0))
+        tr_s = tier_ref[i]
         # dynamic rows: page map decides DRAM vs the precomputed CXL
         # target (level-2 pages hit the SSD target instead); static
         # rows use the precomputed target verbatim
@@ -819,146 +859,108 @@ def _mesi_dyn_kernel(addr_ref, w_ref, core_ref, tier_ref, sc_ref, ptl_ref,
                         jnp.where(intent == 0, 0,
                                   jnp.where(intent >= 2, ssd_t, tr_s)),
                         tr_s)
-        acc_s = _mesi_access(planes, acc_s, a_raw, w_ref[0, 0, i],
-                             core_ref[0, 0, i], tgt, base_t + i, meas,
-                             lay=lay, n_targets=n_targets)
-        counts_s[page] = counts_s[page] + v
+        st = _mesi_access(planes, st, a_raw, w_ref[i], core_ref[i], tgt,
+                          base_t + i, meas, lay=lay, n_targets=n_targets)
+        counts[q, :] = counts[q, :] + jnp.where(at, v, 0)
         sel = jnp.where(flag != 0, intent, tgt)
-        return acc_t + v, acc_d + v * (sel == 0).astype(jnp.int32), acc_s
+        return acc_t + v, acc_d + v * i32(sel == 0), st
 
+    zero = jnp.int32(0)
     acc_t, acc_d, stats[...] = jax.lax.fori_loop(
-        0, slot_len, body, (jnp.int32(0), jnp.int32(0), stats[...]))
+        0, block, body, (zero, zero, stats[...]))
+    acc[0] = jnp.where(k == 0, 0, acc[0]) + acc_t
+    acc[1] = jnp.where(k == 0, 0, acc[1]) + acc_d
 
-    # ---- epoch-boundary promotion/demotion decision ----
-    boundary = ((eidx + 1) % per) == 0
-    do_mig = boundary & (bud > 0)
-    mig_i = do_mig.astype(jnp.int32)
-    km = jnp.int32(k_max)
-    page_ids = jax.lax.broadcasted_iota(jnp.int32, (n_p, 1), 0)[:, 0]
-    pvalid = page_ids < npg
-    pmap = pmap_s[...]
-    counts = counts_s[...]
-    is_cxl = (pmap == 1) & pvalid
-    is_dram = (pmap == 0) & pvalid
-    hot = is_cxl & (counts >= thr)
-    n_hot = hot.sum().astype(jnp.int32)
-    n_dram = is_dram.sum().astype(jnp.int32)
-    # closed-form counts of the reference's top-k mask sums (every min
-    # the rank/validity masks imply, including the top-k width itself)
-    n_want = jnp.minimum(jnp.minimum(n_hot, bud), km)
-    free = jnp.maximum(cap - n_dram, 0)
-    n_dem_needed = jnp.clip(n_want - free, 0, bud)
-    n_dem = jnp.minimum(jnp.minimum(n_dem_needed, n_dram), km) * mig_i
-    n_pro = jnp.minimum(jnp.minimum(n_want, free + n_dem), km) * mig_i
-    neg = jnp.int32(-1)
-    pkey = jnp.where(hot, encode_hot_key(counts, page_ids, n_p), neg)
-    dkey = jnp.where(is_dram,
-                     encode_hot_key(jnp.int32(count_bound) - counts,
-                                    page_ids, n_p), neg)
+    @pl.when(k == n_blocks - 1)
+    def _slot_end():
+        out = pl.ds(slot % _SLOT_ROWS, 1)
 
-    # iterative argmax over the injective keys selects exactly the pages
-    # lax.top_k would (keys are distinct wherever a take can happen)
-    def mig_body(r, sel):
-        pk, dk, pro_l, dem_l = sel
-        ri = jnp.int32(r)
-        pi = jnp.argmax(pk).astype(jnp.int32)
-        take_p = (ri < n_pro).astype(jnp.int32)
-        pmap_s[pi] = jnp.where(ri < n_pro, 0, pmap_s[pi])
-        pro_l = pro_l + ptl_ref[0, pi, :] * take_p
-        pk = pk.at[pi].set(neg)
-        di = jnp.argmax(dk).astype(jnp.int32)
-        take_d = (ri < n_dem).astype(jnp.int32)
-        pmap_s[di] = jnp.where(ri < n_dem, 1, pmap_s[di])
-        dem_l = dem_l + ptl_ref[0, di, :] * take_d
-        dk = dk.at[di].set(neg)
-        return pk, dk, pro_l, dem_l
+        def field(f, x):
+            return jnp.where(lane == SLOT_LANE + f, x, 0)
 
-    zt = jnp.zeros((n_targets,), jnp.int32)
-    _, _, pro_l, dem_l = jax.lax.fori_loop(
-        0, k_max, mig_body, (pkey, dkey, zt, zt))
+        slot_out[out, :] = (stats[...] + field(0, acc[0]) + field(1, acc[1])
+                            + field(4, meas))
+        boundary = ((eidx + 1) % per) == 0
+        shape = pmap.shape
+        pid = (jax.lax.broadcasted_iota(jnp.int32, shape, 0) * 128
+               + jax.lax.broadcasted_iota(jnp.int32, shape, 1))
+        pvalid = pid < npg
+        km = jnp.int32(k_max)
 
-    # promotions read the page from its CXL endpoints + write it to
-    # DRAM; demotions read DRAM + write the CXL endpoints
-    migr_s[...] = migr_s[...] + pro_l.at[0].add(n_dem * lpp)
-    migw_s[...] = migw_s[...] + dem_l.at[0].add(n_pro * lpp)
+        def total(mask):
+            return jnp.sum(i32(mask), axis=(0, 1), keepdims=True)
 
-    # ---- three-tier SSD stage (tiering_dyn._ssd_stage twin) ----
-    ssd_i = (do_mig & (ssd_t > 0)).astype(jnp.int32)
-    pmap2 = pmap_s[...]
-    hot2 = (pmap2 == 2) & pvalid & (counts >= thr)
-    n_sup = jnp.minimum(jnp.minimum(hot2.sum().astype(jnp.int32), bud),
-                        km) * ssd_i
-    skey = jnp.where(hot2, encode_hot_key(counts, page_ids, n_p), neg)
+        def key(hotness, mask):
+            return jnp.where(mask, encode_hot_key(hotness, pid, n_p), -1)
 
-    def sup_body(r, sel):
-        sk, sup_l = sel
-        ri = jnp.int32(r)
-        si = jnp.argmax(sk).astype(jnp.int32)
-        take_s = (ri < n_sup).astype(jnp.int32)
-        pmap_s[si] = jnp.where(ri < n_sup, 1, pmap_s[si])
-        sup_l = sup_l + ptl_ref[0, si, :] * take_s
-        sk = sk.at[si].set(neg)
-        return sk, sup_l
+        def lines(mask):
+            """(1, STAT_LANES) row: the pages' lines per target lane."""
+            return sum(jnp.where(lane == t, jnp.sum(
+                jnp.where(mask, ptl_ref[t], 0), axis=(0, 1),
+                keepdims=True), 0) for t in range(n_targets))
 
-    _, sup_l = jax.lax.fori_loop(0, k_max, sup_body, (skey, zt))
-    pmap3 = pmap_s[...]
-    is_l1 = (pmap3 == 1) & pvalid
-    n_l1 = is_l1.sum().astype(jnp.int32)
-    over = jnp.clip(n_l1 - l1cap, 0, bud)
-    n_over = jnp.minimum(jnp.minimum(over, n_l1), km) * ssd_i
-    okey = jnp.where(is_l1,
-                     encode_hot_key(jnp.int32(count_bound) - counts,
-                                    page_ids, n_p), neg)
+        # ---- epoch-boundary promotion/demotion decision ----
+        @pl.when(boundary & (bud > 0))
+        def _migrate():
+            pm, cn = pmap[...], counts[...]
+            is_cxl = (pm == 1) & pvalid
+            is_dram = (pm == 0) & pvalid
+            hot = is_cxl & (cn >= thr)
+            n_dram = total(is_dram)
+            # closed-form counts of the reference's top-k mask sums
+            # (every min the rank/validity masks imply, including the
+            # top-k width itself)
+            n_want = jnp.minimum(jnp.minimum(total(hot), bud), km)
+            free = jnp.maximum(cap - n_dram, 0)
+            n_dem_needed = jnp.clip(n_want - free, 0, bud)
+            n_dem = jnp.minimum(jnp.minimum(n_dem_needed, n_dram), km)
+            n_pro = jnp.minimum(jnp.minimum(n_want, free + n_dem), km)
+            pro = _top_keys(key(cn, hot), n_pro, k_max)
+            dem = _top_keys(key(count_bound - cn, is_dram), n_dem, k_max)
+            pmap[...] = jnp.where(pro, 0, jnp.where(dem, 1, pm))
+            # promotions read the page from its CXL endpoints + write it
+            # to DRAM; demotions read DRAM + write the CXL endpoints
+            migr[...] += lines(pro) + jnp.where(lane == 0, n_dem * lpp, 0)
+            migw[...] += lines(dem) + jnp.where(lane == 0, n_pro * lpp, 0)
+            slot_out[out, :] += field(2, n_pro) + field(3, n_dem)
 
-    def over_body(r, sel):
-        ok, over_l = sel
-        ri = jnp.int32(r)
-        oi = jnp.argmax(ok).astype(jnp.int32)
-        take_o = (ri < n_over).astype(jnp.int32)
-        pmap_s[oi] = jnp.where(ri < n_over, 2, pmap_s[oi])
-        over_l = over_l + ptl_ref[0, oi, :] * take_o
-        ok = ok.at[oi].set(neg)
-        return ok, over_l
+            # ---- three-tier SSD stage (tiering_dyn._ssd_stage twin) ----
+            @pl.when(ssd_t > 0)
+            def _ssd():
+                pm2 = pmap[...]
+                hot2 = (pm2 == 2) & pvalid & (cn >= thr)
+                n_sup = jnp.minimum(jnp.minimum(total(hot2), bud), km)
+                sup = _top_keys(key(cn, hot2), n_sup, k_max)
+                pm3 = jnp.where(sup, 1, pm2)
+                is_l1 = (pm3 == 1) & pvalid
+                n_l1 = total(is_l1)
+                over = jnp.clip(n_l1 - l1cap, 0, bud)
+                n_over = jnp.minimum(jnp.minimum(over, n_l1), km)
+                down = _top_keys(key(count_bound - cn, is_l1), n_over, k_max)
+                pmap[...] = jnp.where(down, 2, pm3)
+                # SSD promotion reads the SSD target + writes the CXL
+                # endpoints; SSD demotion the reverse
+                migr[...] += lines(down) + jnp.where(lane == ssd_t,
+                                                     n_sup * lpp, 0)
+                migw[...] += lines(sup) + jnp.where(lane == ssd_t,
+                                                    n_over * lpp, 0)
+                slot_out[out, :] += field(2, n_sup) + field(3, n_over)
 
-    _, over_l = jax.lax.fori_loop(0, k_max, over_body, (okey, zt))
-    # SSD promotion reads the SSD target + writes the CXL endpoints;
-    # SSD demotion the reverse
-    migr_s[...] = migr_s[...] + over_l.at[ssd_t].add(n_sup * lpp)
-    migw_s[...] = migw_s[...] + sup_l.at[ssd_t].add(n_over * lpp)
-    counts_s[...] = jnp.where(boundary, 0, counts_s[...])
-
-    # per-slot outputs (every slot publishes its own block)
-    slots_ref[0, 0, :] = jnp.stack([acc_t, acc_d, n_pro + n_sup,
-                                    n_dem + n_over])
-    snaps_ref[0, 0, :] = stats[0, :ns]
-    meas_ref[0, 0] = meas
-
-    # publish this batch row's final carry after its last slot
-    @pl.when(j == n_slots - 1)
-    def _out():
-        stats_ref[0, :] = stats[0, :ns]
-        l1t_ref[0] = l1t[...]
-        l1u_ref[0] = l1u[...]
-        l1s_ref[0] = l1s[...]
-        l2t_ref[0] = l2t[...]
-        l2u_ref[0] = l2u[...]
-        l2s_ref[0] = l2s[...]
-        l2tier_ref[0] = l2tier[...]
-        l2sh_ref[0] = l2sh[...]
-        pmap_ref[0, :] = pmap_s[...]
-        counts_ref[0, :] = counts_s[...]
-        migr_ref[0, :] = migr_s[...]
-        migw_ref[0, :] = migw_s[...]
+        @pl.when(boundary)
+        def _reset():
+            counts[...] = jnp.zeros(shape, jnp.int32)
 
 
 @functools.partial(jax.jit, static_argnames=("params", "k_max",
-                                             "count_bound", "interpret"))
+                                             "count_bound", "chunk",
+                                             "interpret"))
 def mesi_dyn_segment(carry, addr: Array, is_write: Array, core: Array,
                      tier: Array, dyn_flag, n_pages, budget, threshold,
                      period, dram_cap, ssd_tid, cxl_cap,
                      page_target_lines, s_warm, s_meas,
                      s_per, *, params: CacheParams, k_max: int,
-                     count_bound: int, interpret: bool = True):
+                     count_bound: int, chunk: int = TRACE_TILE,
+                     interpret: bool = True):
     """Advance the batched epoch carry over a (B, E, slot_len) segment.
 
     The carry is exactly :func:`repro.core.tiering_dyn.init_dyn_carry`'s
@@ -968,6 +970,21 @@ def mesi_dyn_segment(carry, addr: Array, is_write: Array, core: Array,
     resilient executor's checkpointed replay) as a backend swap:
     segments may alternate freely between this kernel and the reference
     scan with bitwise-identical carries and per-slot outputs.
+
+    The grid is (B, E * blocks per slot).  A slot streams as 1-D SMEM
+    trace blocks of ``chunk`` entries (rounded up to whole
+    :data:`TRACE_TILE` tiles when compiled), its tail padded with
+    sentinels, which touch nothing.  The page map and page counters are
+    lane-dense VMEM planes of 128 pages a row, in whole (8, 128) tiles,
+    read and counted by row per access and reduced whole at an epoch
+    boundary; the per-row scalars sit in SMEM.  VMEM holds one copy of
+    the carry, as in
+    :func:`mesi_segment`; the kernel compiles with its blocks' VMEM
+    (:func:`vmem_limit_bytes` with ``n_pages``) and runs on a TPU where
+    that fits the chip (:func:`fits_chip`).  The stat snapshot, slot
+    counters and measurement flag of each slot come out as one
+    128-lane row, so ``nstats(params.n_targets)`` may not pass
+    :data:`SLOT_LANE`.
 
     Returns ``(carry, slots, snaps, meas)``: the advanced carry, the
     (B, E, 4) per-slot counters (:data:`repro.core.tiering_dyn.
@@ -981,71 +998,80 @@ def mesi_dyn_segment(carry, addr: Array, is_write: Array, core: Array,
     n_p = int(page_target_lines.shape[1])
     n_t = params.n_targets
     ns = nstats(n_t)
+    if ns > SLOT_LANE:
+        raise ValueError(f"{n_t} targets' {ns} counters leave no lanes for "
+                         f"the per-slot fields (at most {SLOT_LANE})")
     lay = Layout.of(params)
     # k_max is a static argname — int() runs at trace time, not on a
     # traced value  # repro-lint: disable=RL201
     k_max = min(int(k_max), n_p)
+    block = min(chunk, slot_len)
+    if not interpret:
+        block = -(-block // TRACE_TILE) * TRACE_TILE
+    n_blocks = -(-slot_len // block)
+    e_out = -(-e // _SLOT_ROWS) * _SLOT_ROWS
+    rows = _page_rows(n_p)
 
     def i32(x):
         return jnp.asarray(x, jnp.int32)
 
+    def page_plane(x):
+        """(..., n_p) -> (..., rows, 128), padding pages never valid."""
+        x = i32(x)
+        x = jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, rows * 128 - n_p)])
+        return x.reshape(x.shape[:-1] + (rows, 128))
+
+    trace = [x.reshape(-1) for x in pad_trace(
+        n_blocks * block, i32(addr), i32(is_write), i32(core), i32(tier))]
     sc = jnp.stack([i32(dyn_flag), i32(n_pages), i32(budget),
                     i32(threshold), i32(period), i32(dram_cap),
                     i32(ssd_tid), i32(cxl_cap),
                     i32(s_warm), i32(s_meas), i32(s_per),
-                    i32(t), i32(eidx)], axis=1)
+                    i32(t), i32(eidx)], axis=1).reshape(-1)
+    carry_in = [_lane_row(stats), *_carry_planes(lay, l1p, l2p),
+                page_plane(pmap), page_plane(counts), _lane_row(mig_rd),
+                _lane_row(mig_wr)]
+    state_specs, state_shape = _state_specs(
+        b, [x.shape[1:] for x in carry_in], squeeze=False)
 
     kernel = functools.partial(
-        _mesi_dyn_kernel, slot_len=slot_len, lay=lay, n_slots=e,
-        n_targets=n_t,
-        n_p=n_p, k_max=k_max, count_bound=count_bound)
-    trace_spec = pl.BlockSpec((1, 1, slot_len), lambda b_, j: (b_, j, 0))
-    sc_spec = pl.BlockSpec((1, len(DYN_SCALARS)), lambda b_, j: (b_, 0))
-    ptl_spec = pl.BlockSpec((1, n_p, n_t), lambda b_, j: (b_, 0, 0))
-    st_spec = pl.BlockSpec((1, ns), lambda b_, j: (b_, 0))
-    l1_spec = pl.BlockSpec((1,) + lay.shape1, lambda b_, j: (b_, 0, 0))
-    l2_spec = pl.BlockSpec((1,) + lay.shape2, lambda b_, j: (b_, 0, 0))
-    pg_spec = pl.BlockSpec((1, n_p), lambda b_, j: (b_, 0))
-    tg_spec = pl.BlockSpec((1, n_t), lambda b_, j: (b_, 0))
-    slots_spec = pl.BlockSpec((1, 1, 4), lambda b_, j: (b_, j, 0))
-    snaps_spec = pl.BlockSpec((1, 1, ns), lambda b_, j: (b_, j, 0))
-    meas_spec = pl.BlockSpec((1, 1), lambda b_, j: (b_, j))
-    carry_specs = [st_spec] + [l1_spec] * 3 + [l2_spec] * 5 \
-        + [pg_spec] * 2 + [tg_spec] * 2
-    out_shape = [
-        jax.ShapeDtypeStruct((b, ns), jnp.int32),
-    ] + [jax.ShapeDtypeStruct((b,) + lay.shape1, jnp.int32)] * 3 \
-        + [jax.ShapeDtypeStruct((b,) + lay.shape2, jnp.int32)] * 5 \
-        + [jax.ShapeDtypeStruct((b, n_p), jnp.int32)] * 2 \
-        + [jax.ShapeDtypeStruct((b, n_t), jnp.int32)] * 2 \
-        + [jax.ShapeDtypeStruct((b, e, 4), jnp.int32),
-           jax.ShapeDtypeStruct((b, e, ns), jnp.int32),
-           jax.ShapeDtypeStruct((b, e), jnp.int32)]
-    scratch = [pltpu.VMEM(lay.shape1, jnp.int32)] * 3 \
-        + [pltpu.VMEM(lay.shape2, jnp.int32)] * 5 \
-        + [pltpu.VMEM((1, STAT_LANES), jnp.int32)] \
-        + [pltpu.VMEM((n_p,), jnp.int32)] * 2 \
-        + [pltpu.VMEM((n_t,), jnp.int32)] * 2
-
-    planes = _carry_planes(lay, l1p, l2p)
+        _mesi_dyn_kernel, block=block, n_blocks=n_blocks,
+        slot_len=slot_len, lay=lay, n_targets=n_t, n_p=n_p, k_max=k_max,
+        count_bound=count_bound)
+    trace_spec = pl.BlockSpec((block,),
+                              lambda b_, g: (b_ * e * n_blocks + g,),
+                              memory_space=pltpu.SMEM)
+    ptl_spec = pl.BlockSpec((None, n_t, rows, 128),
+                            lambda b_, g: (b_, 0, 0, 0),
+                            pipeline_mode=pl.Buffered(1))
+    slot_spec = pl.BlockSpec((None, _SLOT_ROWS, STAT_LANES),
+                             lambda b_, g: (b_, g // (n_blocks * _SLOT_ROWS),
+                                            0))
     outs = pl.pallas_call(
         kernel,
-        grid=(b, e),
-        in_specs=[trace_spec] * 4 + [sc_spec, ptl_spec]
-        + [l1_spec] * 3 + [l2_spec] * 5
-        + [st_spec] + [pg_spec] * 2 + [tg_spec] * 2,
-        out_specs=carry_specs + [slots_spec, snaps_spec, meas_spec],
-        out_shape=out_shape,
-        scratch_shapes=scratch,
+        grid=(b, e * n_blocks),
+        in_specs=[trace_spec] * 4 + [pl.BlockSpec(memory_space=pltpu.SMEM),
+                                     ptl_spec]
+        + [pl.BlockSpec(memory_space=pl.ANY)] * len(carry_in),
+        out_specs=state_specs + [slot_spec],
+        out_shape=state_shape + [
+            jax.ShapeDtypeStruct((b, e_out, STAT_LANES), jnp.int32)],
+        scratch_shapes=[pltpu.SMEM((2,), jnp.int32)],
+        # the carry-in becomes the carry-out
+        input_output_aliases={6 + k: k for k in range(len(carry_in))},
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=vmem_limit_bytes(params, n_pages=n_p)),
         interpret=interpret,
-    )(i32(addr), i32(is_write), i32(core), i32(tier), sc,
-      i32(page_target_lines), *planes, i32(stats), i32(pmap),
-      i32(counts), i32(mig_rd), i32(mig_wr))
+    )(*trace, sc, page_plane(jnp.swapaxes(i32(page_target_lines), 1, 2)),
+      *carry_in)
 
-    stats_o = outs[0]
     l1p_o, l2p_o = _pack_planes(lay, outs[1:9])
-    pmap_o, counts_o, migr_o, migw_o, slots, snaps, meas = outs[9:]
-    new_carry = (l1p_o, l2p_o, stats_o, t + jnp.int32(e * slot_len),
-                 pmap_o, counts_o, migr_o, migw_o,
+    pmap_o, counts_o = (x.reshape(b, rows * 128)[:, :n_p]
+                        for x in outs[9:11])
+    per_slot = outs[13][:, :e]
+    new_carry = (l1p_o, l2p_o, outs[0][:, 0, :ns],
+                 t + jnp.int32(e * slot_len), pmap_o, counts_o,
+                 outs[11][:, 0, :n_t], outs[12][:, 0, :n_t],
                  eidx + jnp.int32(e))
-    return new_carry, slots, snaps, meas
+    return (new_carry, per_slot[..., SLOT_LANE:SLOT_LANE + 4],
+            per_slot[..., :ns], per_slot[..., SLOT_LANE + 4])

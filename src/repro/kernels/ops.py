@@ -74,20 +74,22 @@ def mesi_dyn_segment(carry, addr: Array, is_write: Array, core: Array,
                      tier: Array, dyn_flag, n_pages, budget, threshold,
                      period, dram_cap, ssd_tid, cxl_cap,
                      page_target_lines, s_warm, s_meas,
-                     s_per, *, params, k_max: int, count_bound: int):
+                     s_per, *, params, k_max: int, count_bound: int,
+                     chunk: int = 1024):
     """Advance the batched epoch carry over a (B, E, slot_len) segment.
 
     The kernel-side twin of :func:`repro.core.tiering_dyn.
     run_dynamic_segment`: same 9-tuple carry and per-slot outputs
     (slots/snapshots/meas), bitwise-equal across dynamic tiering,
-    three-tier SSD, sampling and static ride-along rows.
+    three-tier SSD, sampling and static ride-along rows.  ``chunk`` is
+    the trace entries of one SMEM block, at most a slot.
     """
     return _mesi_dyn_segment(carry, addr, is_write, core, tier, dyn_flag,
                              n_pages, budget, threshold, period, dram_cap,
                              ssd_tid, cxl_cap,
                              page_target_lines, s_warm, s_meas, s_per,
                              params=params, k_max=k_max,
-                             count_bound=count_bound,
+                             count_bound=count_bound, chunk=chunk,
                              interpret=_interpret())
 
 

@@ -71,7 +71,7 @@ def spec(backend="reference", **kw):
     ("cpu", None, False, "reference"),
     ("cpu", None, True, "reference"),
     ("tpu", None, False, "pallas"),     # the static program's kernel
-    ("tpu", None, True, "reference"),   # the epoch kernel does not lower
+    ("tpu", None, True, "pallas"),      # the epoch program's kernel
     ("tpu", "reference", False, "reference"),
     ("cpu", "pallas", True, "pallas"),
 ])
@@ -99,6 +99,28 @@ def test_default_backend_keeps_the_scan_where_the_state_outgrows_vmem(
                       l2_bytes=l2_mib * 2 ** 20)
     assert engine.resolve_backend(None, p) == want
     assert engine.resolve_backend("pallas", p) == "pallas"
+
+
+@pytest.mark.parametrize("l2_mib,n_pages,want", [
+    (2, 2048, "pallas"),         # the hotcold cell: 0.75 MiB in all
+    (256, 4098, "pallas"),       # 80.05 MiB of cache planes, 0.15 of pages
+    (256, 2 ** 21, "reference"), # and 56 MiB of page planes: too many
+    (512, 2048, "reference"),    # 160.05 MiB of cache planes alone
+])
+def test_default_epoch_backend_keeps_the_scan_where_its_state_outgrows_vmem(
+        monkeypatch, l2_mib, n_pages, want):
+    """The epoch kernel holds the cache planes and, per page, its map,
+    counters and lines per target: both count against the chip's VMEM."""
+    from repro.kernels import cache_sim, ops
+    monkeypatch.setattr(ops, "platform", lambda: "tpu")
+    monkeypatch.setattr(cache_sim, "chip_vmem_bytes",
+                        lambda device_kind=None:
+                        cache_sim.VMEM_BYTES["TPU v5 lite"])
+    p = C.CacheParams(cores=4, n_targets=5, l2_bytes=l2_mib * 2 ** 20)
+    assert engine.resolve_backend(None, p, epoch=True,
+                                  n_pages=n_pages) == want
+    assert engine.resolve_backend("pallas", p, epoch=True,
+                                  n_pages=n_pages) == "pallas"
 
 
 def test_unknown_backend_is_refused():
@@ -313,6 +335,80 @@ def test_sampled_sweep_parity():
         spec("pallas", tiering=DYN_AXIS, sampling=sampling), CACHE,
         TIMING)
     assert rows == legacy
+
+
+# ---------------------------------------------------------------------------
+# the epoch kernel's segment against the scan's, shape by shape
+# ---------------------------------------------------------------------------
+SEGMENT_CASES = {
+    # name: (rows, slots, slot, chunk, pages, row scalars)
+    # a slot streamed as four SMEM trace blocks
+    "four_blocks_a_slot": (2, 3, 256, 64, 20, {}),
+    # blocks that do not divide the slot: its tail is sentinel padding
+    "padded_last_block": (1, 3, 200, 96, 20, {}),
+    # epochs of three slots: two slots in three are no boundary
+    "epoch_of_three_slots": (2, 6, 64, 64, 20, dict(period=3, thr=4)),
+    # 200 pages, two page rows of which 72 lanes pad, the last ten
+    # pages not migration-eligible, DRAM capacity forcing demotions
+    "pages_not_lane_aligned": (2, 4, 128, 128, 200,
+                               dict(n_pages=190, cap=12)),
+    # a budget beyond the hot pages: every hot page promotes
+    "budget_over_hot_pages": (1, 4, 128, 128, 40, dict(budget=16)),
+    # three tiers under a sampled window
+    "three_tier_sampled": (1, 6, 64, 32, 20,
+                           dict(ssd=True, l1cap=2, s_per=3)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(SEGMENT_CASES))
+def test_epoch_kernel_segment_matches_the_scan(case):
+    """One segment of ``mesi_dyn_segment`` against the reference's
+    ``run_dynamic_segment``: carries and per-slot outputs bitwise equal
+    from a carry mid-run (pages on every level, a running clock and
+    slot index, nonzero counters and migration totals)."""
+    from repro.kernels import ops
+    b, e, slot, chunk, n_p, kw = SEGMENT_CASES[case]
+    rng = np.random.default_rng(len(case))
+    n_t = CACHE.n_targets + 1 if kw.get("ssd") else CACHE.n_targets
+    p = C.CacheParams(l1_bytes=2048, l1_ways=2, l2_bytes=8192, l2_ways=4,
+                      cores=2, n_targets=n_t)
+    shape = (b, e, slot)
+    # 70% of accesses on four hot pages, the rest anywhere
+    addr = np.where(rng.random(shape) < 0.7,
+                    rng.integers(0, 4 * 64, shape),
+                    rng.integers(0, n_p * 64, shape)).astype(np.int32)
+    addr[-1, -1, slot // 2:] = engine.SENTINEL
+    trace = [addr, rng.integers(0, 2, shape), rng.integers(0, 2, shape),
+             rng.integers(1, n_t, shape)]
+    one = np.ones((b,), np.int32)
+    ssd_tid = n_t - 1 if kw.get("ssd") else 0
+    scalars = [one, one * kw.get("n_pages", n_p), one * kw.get("budget", 4),
+               one * kw.get("thr", 2), one * kw.get("period", 1),
+               one * kw.get("cap", 1 << 30), one * ssd_tid,
+               one * kw.get("l1cap", 1 << 30),
+               rng.integers(0, 40, (b, n_p, n_t)), one, one * 2,
+               one * kw.get("s_per", 0)]
+    k_max = kw.get("budget", 4)
+    count_bound = kw.get("period", 1) * slot + 1
+    pmap0 = rng.integers(0, 3 if ssd_tid else 2, (b, n_p))
+    carry = tiering_dyn.init_dyn_carry(p, pmap0)
+    # start mid-run: one reference segment first
+    carry, *_ = tiering_dyn.run_dynamic_segment(
+        p, k_max, count_bound, carry, *(x[:, :1] for x in trace), *scalars)
+    want = tiering_dyn.run_dynamic_segment(p, k_max, count_bound, carry,
+                                           *trace, *scalars)
+    got = ops.mesi_dyn_segment(carry, *trace, *scalars, params=p,
+                               k_max=k_max, count_bound=count_bound,
+                               chunk=chunk)
+    names = ("l1p", "l2p", "stats", "t", "page_map", "counts", "mig_read",
+             "mig_write", "eidx", "slots", "snapshots", "meas")
+    for name, g, w in zip(names, [*got[0], *got[1:]], [*want[0], *want[1:]]):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+    slots = np.asarray(want[1])
+    assert slots[..., 2].sum() > 0            # the case migrates
+    if case == "budget_over_hot_pages":
+        assert slots[..., 2].max() < kw["budget"]
 
 
 # ---------------------------------------------------------------------------

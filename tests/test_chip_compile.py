@@ -9,14 +9,13 @@ nothing about results or speed.
 Geometry is the paper's Table-I host (4 cores, 64 KiB 8-way L1, 2 MiB
 16-way L2) with five route targets (``switched(4)``); batch and segment
 are cut so each program compiles in seconds.  The static program's
-Pallas MESI kernels (``mesi_cache_sim``, ``mesi_segment``: what a TPU
-runs by default) compile with ``interpret=False`` at two and five
+Pallas MESI kernels (``mesi_cache_sim``, ``mesi_segment``) and the
+epoch program's (``mesi_dyn_segment``), what a TPU runs by default,
+compile with ``interpret=False``: the static ones at two and five
 targets and two batch widths, and at one AMD EPYC 9004 CCD (8 cores, a
-32 MiB L3), and the VMEM rule that keeps a larger cache on the
-reference scan matches what the compiler accepts.  The
-epoch program's kernel (``mesi_dyn_segment``) is not here: Mosaic still
-refuses its (1, 1, slot) trace blocks, which break the 8x128 tiling
-rule.  One
+32 MiB L3), the epoch one at five targets and two batch widths.  The
+VMEM rule that keeps a larger cache on the reference scan matches what
+the compiler accepts, for both programs.  One
 program too big for the chip's HBM checks that the compiler's refusal
 reads as an out-of-memory to the resilient executor, which then narrows
 the segment instead of giving up.
@@ -155,20 +154,58 @@ def test_vmem_rule_matches_the_compiler(one_chip, no_compile_cache, l2_mib,
             lowered.compile()
 
 
-def test_dynamic_segment_compiles(one_chip, no_compile_cache):
-    page_map0 = jax.ShapeDtypeStruct((BATCH, PAGES), jnp.int32)
+def _dyn_args(p, batch, slots, slot, pages, sharding):
+    """Shapes of an epoch segment's carry, trace and per-row scalars."""
+    page_map0 = jax.ShapeDtypeStruct((batch, pages), jnp.int32)
     carry = _shapes(jax.eval_shape(
-        functools.partial(tiering_dyn.init_dyn_carry, PARAMS), page_map0),
-        one_chip)
+        functools.partial(tiering_dyn.init_dyn_carry, p), page_map0),
+        sharding)
 
     def arr(*shape):
-        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=one_chip)
+        return jax.ShapeDtypeStruct(shape, jnp.int32, sharding=sharding)
 
-    trace = [arr(BATCH, 1, SLOT)] * 4
-    scalars = ([arr(BATCH)] * 8 + [arr(BATCH, PAGES, PARAMS.n_targets)]
-               + [arr(BATCH)] * 3)
+    trace = [arr(batch, slots, slot)] * 4
+    scalars = ([arr(batch)] * 8 + [arr(batch, pages, p.n_targets)]
+               + [arr(batch)] * 3)
+    return [carry, *trace, *scalars]
+
+
+@pytest.mark.parametrize("batch", [1, 8])
+def test_epoch_kernel_compiles(one_chip, no_compile_cache, batch):
+    """The epoch program's kernel lowers through Mosaic and compiles:
+    two slots of 4,096 accesses, four SMEM trace blocks each."""
+    compiled = cache_sim.mesi_dyn_segment.lower(
+        *_dyn_args(PARAMS, batch, 2, SLOT, PAGES, one_chip), params=PARAMS,
+        k_max=8, count_bound=SLOT + 1, interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("l2_mib", [256, 512])
+def test_epoch_vmem_rule_matches_the_compiler(one_chip, no_compile_cache,
+                                              l2_mib):
+    """As for the static kernels: the largest 16-way L2 the rule admits
+    for the epoch kernel, page planes included, compiles, and the next
+    size up runs out of VMEM."""
+    p = cache_mod.CacheParams(cores=4, n_targets=2,
+                              l2_bytes=l2_mib * 2 ** 20)
+    fits = cache_sim.fits_chip(p, _kind(one_chip), n_pages=PAGES)
+    assert fits == (l2_mib == 256)
+    lowered = cache_sim.mesi_dyn_segment.lower(
+        *_dyn_args(p, 8, 1, cache_sim.TRACE_TILE, PAGES, one_chip),
+        params=p, k_max=8, count_bound=cache_sim.TRACE_TILE + 1,
+        interpret=False)
+    if fits:
+        assert "tpu_custom_call" in lowered.compile().as_text()
+    else:
+        with pytest.raises(jax.errors.JaxRuntimeError,
+                           match="memory space vmem"):
+            lowered.compile()
+
+
+def test_dynamic_segment_compiles(one_chip, no_compile_cache):
     compiled = tiering_dyn._dyn_segment_stepper(True).lower(
-        PARAMS, 8, SLOT + 1, carry, *trace, *scalars).compile()
+        PARAMS, 8, SLOT + 1,
+        *_dyn_args(PARAMS, BATCH, 1, SLOT, PAGES, one_chip)).compile()
     assert compiled.memory_analysis().temp_size_in_bytes > 0
 
 

@@ -196,6 +196,29 @@ def test_program_span_counts_the_state_and_the_kernels_vmem(backend,
         if backend == "pallas" else 0)
 
 
+@pytest.mark.parametrize("backend", ["reference", "pallas"])
+def test_epoch_program_span_counts_the_kernels_vmem(backend):
+    from repro.kernels import cache_sim
+    rng = np.random.default_rng(8)
+    b, slot, pages = 2, 128, 6
+    addr = jnp.asarray(rng.integers(0, pages * 64, (b, 2 * slot)),
+                       jnp.int32)
+    z = jnp.zeros(addr.shape, jnp.int32)
+    one = jnp.ones((b,), jnp.int32)
+    _, recs = _recorded(lambda: td.run_dynamic(
+        CACHE, addr, z, z, z + 1, slot_len=slot, k_max=2, dyn_flag=one,
+        page_map0=jnp.ones((b, pages), jnp.int32), n_pages=pages * one,
+        budget=2 * one, threshold=one, period=one, dram_cap=pages * one,
+        page_target_lines=jnp.zeros((b, pages, 2), jnp.int32)
+        .at[:, :, 1].set(64), backend=backend))
+    (program,) = _named(recs, "sweep.program")
+    assert program.counters["program"] == "epoch"
+    assert program.counters["backend"] == backend
+    assert program.counters["vmem_limit_bytes"] == (
+        cache_sim.vmem_limit_bytes(CACHE, n_pages=pages)
+        if backend == "pallas" else 0)
+
+
 def test_off_records_nothing_opens_nothing_and_listens_to_nothing(
         monkeypatch):
     from jax._src import monitoring

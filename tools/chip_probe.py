@@ -2,7 +2,7 @@
 """Time the sweep engine's device programs on a TPU, one phase a process.
 
     python tools/chip_probe.py static  [--batches 1 8 60 120]
-    python tools/chip_probe.py dynamic [--batches 1 60]
+    python tools/chip_probe.py dynamic [--batches 1 2]
     python tools/chip_probe.py donation
 
 ``static`` times one warm call of the static segment program
@@ -16,7 +16,8 @@ reference scan and the Pallas kernel); it prints
 microseconds per scan step and whether the two backends' carries are
 bitwise equal.  ``dynamic`` does the same for one 4,096-access epoch
 slot of the epoch program (``tiering_dyn.run_dynamic_segment``, 1,026
-pages), on the reference scan.
+pages, a migration at the slot's end), comparing the carries and the
+per-slot outputs.
 ``donation`` says whether a segment call deletes its input carry with
 ``donate=False`` and with ``donate=True``.
 
@@ -88,7 +89,9 @@ def static(tag, rng, batches) -> None:
 
 
 def dynamic(tag, rng, batches) -> None:
+    import jax
     import jax.numpy as jnp
+    import numpy as np
 
     from repro.core import cache as cache_mod
     from repro.core import tiering_dyn
@@ -104,14 +107,21 @@ def dynamic(tag, rng, batches) -> None:
                    jnp.full((b, PAGES, p.n_targets), 16, jnp.int32),
                    ones * 0, ones * 0, ones * 0]
         carry = tiering_dyn.init_dyn_carry(p, jnp.ones((b, PAGES), jnp.int32))
-
-        def call():
-            return tiering_dyn.run_dynamic_segment(p, 8, SLOT + 1, carry,
-                                                   *trace, *scalars)
-        first, warm = _timed(call), _timed(call)
-        print(f"{tag} epoch segment, Table I geometry, {PAGES} pages, "
-              f"B={b}: first {first:.2f} s, warm {warm:.3f} s, "
-              f"{warm / SLOT * 1e6:.1f} us per step", flush=True)
+        outs = []
+        for backend in ("reference", "pallas"):
+            def call(backend=backend):
+                return tiering_dyn.run_dynamic_segment(
+                    p, 8, SLOT + 1, carry, *trace, *scalars,
+                    backend=backend)
+            first, warm = _timed(call), _timed(call)
+            outs.append(jax.tree_util.tree_leaves(jax.device_get(call())))
+            print(f"{tag} epoch segment, Table I geometry, {PAGES} pages, "
+                  f"B={b}, {backend}: first {first:.2f} s, warm {warm:.4f} s, "
+                  f"{warm / SLOT * 1e6:.3f} us per step", flush=True)
+        same = all(np.array_equal(x, y) for x, y in zip(*outs))
+        print(f"{tag} epoch segment, Table I geometry, B={b}: carries and "
+              f"per-slot outputs bitwise equal across backends: {same}",
+              flush=True)
 
 
 def donation(tag, rng, _batches) -> None:
@@ -132,7 +142,7 @@ def donation(tag, rng, _batches) -> None:
 
 
 PHASES = {"static": (static, (1, 8, 60, 120)),
-          "dynamic": (dynamic, (1, 60)),
+          "dynamic": (dynamic, (1, 2)),
           "donation": (donation, ())}
 
 
